@@ -11,36 +11,16 @@ engine too (both rows run them), so the remaining delta is the fabric's
 per-shard convergence mask on the tail iterations.  On a real
 multi-device mesh the same program adds actual parallel speedup on top.
 
-If the current process has a single device, the sharded leg runs in a
-subprocess (``python -m repro.launch.shard_run --mode bench --json``)
-that owns its XLA_FLAGS; the in-process leg is preferred because it
-shares jit caches with the rest of the suite.
+The suite runs in-process on the devices this process has: a one-device
+process measures the sharded loop over a one-device mesh.  It never
+starts a child process, which could not reach a chip this process holds.
+The CI fabric job runs it under
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``, a simulated mesh.
 """
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-
 from benchmarks.common import emit, timeit
-
-DEVICES = 4
-
-
-def _bench_subprocess(n: int, memory_bytes: int, repeats: int) -> dict:
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["PYTHONPATH"] = env.get("PYTHONPATH", "src") or "src"
-    proc = subprocess.run(
-        [sys.executable, "-m", "repro.launch.shard_run", "--mode", "bench",
-         "--json", "--devices", str(DEVICES), "--n", str(n),
-         "--memory-bytes", str(memory_bytes), "--repeats", str(repeats)],
-        capture_output=True, text=True, timeout=1800, env=env)
-    if proc.returncode != 0:
-        raise RuntimeError(f"shard_run bench failed:\n{proc.stderr[-2000:]}")
-    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def _bench_inprocess(n: int, memory_bytes: int, repeats: int) -> dict:
@@ -77,10 +57,7 @@ def run(quick: bool = True) -> None:
 
     import jax
 
-    if jax.device_count() >= 2:
-        res = _bench_inprocess(n, memory_bytes, repeats)
-    else:
-        res = _bench_subprocess(n, memory_bytes, repeats)
+    res = _bench_inprocess(n, memory_bytes, repeats)
 
     from benchmarks.bench_build import engine_stamp
 

@@ -154,8 +154,8 @@ def run(quick: bool = True) -> None:
     if use_pallas:
         from repro.kernels.packed_gather import suffix_lcp_words
 
-        lcp_words_fn = jax.jit(lambda st, a, b: suffix_lcp_words(
-            st, a, b, W, interpret=jax.default_backend() != "tpu"))
+        lcp_words_fn = jax.jit(
+            lambda st, a, b: suffix_lcp_words(st, a, b, W))
     else:
         lcp_words_fn = jax.jit(
             lambda st, a, b: kref.suffix_lcp_words_ref(st, a, b, W))

@@ -18,10 +18,12 @@
 
 ``python -m benchmarks.run``            — quick pass over everything
 ``python -m benchmarks.run --full``     — paper-scale (slower) settings
-``python -m benchmarks.run --smoke``    — CI mode: quick settings, errors
-                                          fatal at exit, intended with --json
+``python -m benchmarks.run --smoke``    — CI mode: quick settings,
+                                          intended with --json
 ``python -m benchmarks.run --json results.json``  — persist rows as JSON
 ``python -m benchmarks.run --only fig9b``
+
+Every mode runs all selected suites and exits non-zero if any errored.
 """
 
 from __future__ import annotations
@@ -37,8 +39,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--smoke", action="store_true",
-                    help="CI smoke pass: quick settings, nonzero exit if any "
-                         "suite errored")
+                    help="CI smoke pass: quick settings")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write all emitted rows to PATH as JSON")
     ap.add_argument("--only", default=None,
@@ -115,7 +116,7 @@ def main() -> None:
             json.dump(payload, f, indent=2)
         print(f"wrote {len(common.RESULTS)} rows to {args.json}", file=sys.stderr)
 
-    if args.smoke and errors:
+    if errors:
         sys.exit(1)
 
 

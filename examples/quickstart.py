@@ -254,13 +254,13 @@ def main():
     #     divergence rows from the packed text via the word-compare LCP
     #     kernel instead of the stored construction state — same nodes.
     #
-    #     Kernel tile shapes come from repro.roofline.autotune: dispatch
-    #     resolves each (backend, kernel, dtype-bits, n-bucket) through
-    #     an on-disk autotune table when one exists (REPRO_AUTOTUNE_TABLE,
-    #     default .repro_autotune.json — written only by explicit sweeps,
-    #     never at import), else the VMEM/HBM roofline model when
-    #     REPRO_AUTOTUNE=model, else the static defaults.  Tiles change
-    #     DMA granularity, never results:
+    #     Kernel tiles (reads per grid step) come from
+    #     repro.roofline.autotune: dispatch resolves each (backend,
+    #     kernel, dtype-bits, n-bucket) through the autotune table the
+    #     environment names (REPRO_AUTOTUNE_TABLE — written only by
+    #     explicit sweeps, never at import), else the VMEM/HBM roofline
+    #     model when REPRO_AUTOTUNE=model, else the static defaults.
+    #     Tiles change the blocking, never results:
     from repro.roofline import autotune
     table = autotune.AutotuneTable()
     table.fill_model("cpu", {"range_gather": 64, "suffix_lcp": 256},

@@ -955,7 +955,8 @@ class EraIndexer:
             s_padded, groups, capacity, self.config.elastic_config(),
             mesh=mesh, stats=report.prepare,
             sort_fuse=(sort_fuse if sort_fuse is not None
-                       else self.config.sort_fuse))
+                       else self.config.sort_fuse),
+            compact=self.config.compaction)
         report.t_prepare = time.perf_counter() - t0
         prefixes, freqs, ell = _flatten_state(groups, states)
         return fabric.ShardedIndex.from_flat(

@@ -53,7 +53,6 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
@@ -130,10 +129,10 @@ def _shard_step(mesh, w: int, use_pallas: bool, word_keys: bool,
             states = live(states)
         return states, jnp.sum(states.area >= 0, axis=1)
 
-    fn = shard_map(one_shard, mesh=mesh,
-                   in_specs=(P(), P(SHARD_AXIS, None)),
-                   out_specs=(P(SHARD_AXIS, None), P(SHARD_AXIS)),
-                   check_rep=False)
+    fn = jax.shard_map(one_shard, mesh=mesh,
+                       in_specs=(P(), P(SHARD_AXIS, None)),
+                       out_specs=(P(SHARD_AXIS, None), P(SHARD_AXIS)),
+                       check_vma=False)
     jitted = jax.jit(fn, donate_argnums=(1,))
     _STEP_CACHE[key] = jitted
     return jitted
@@ -165,10 +164,12 @@ def sharded_prepare(
     stats: PrepareStats | None = None,
     max_iters: int = 10_000,
     sort_fuse: bool | None = None,
+    compact: bool | None = None,
 ) -> PrepareState:
     """:func:`repro.core.prepare.subtree_prepare_batch` over a device
     mesh: groups split into contiguous per-shard blocks, one SPMD step
-    per elastic iteration, per-shard convergence mask.
+    per elastic iteration, per-shard convergence mask.  ``sort_fuse`` /
+    ``compact`` resolve as in the single-device engine.
 
     Returns the final (G, F) state (sliced back to the real group count;
     dummy padding groups never reach the caller) — bit-identical to the
@@ -182,6 +183,8 @@ def sharded_prepare(
     word_keys = kops._use_word_compare()
     if sort_fuse is None:
         sort_fuse = kops._use_sort_fuse()
+    if compact is None:
+        compact = kops._use_compaction()
 
     states = _pad_group_axis(init_batch(groups, capacity), g_pad)
     states = jax.device_put(
@@ -206,7 +209,8 @@ def sharded_prepare(
             # tail compaction: once every group's active count fits in
             # half the state width, sort only the active rows (the
             # pow2 bucket keeps program variants to ~log2(F) per w)
-            f_prime = compaction_width(int(n_active.max()), capacity)
+            f_prime = (compaction_width(int(n_active.max()), capacity)
+                       if compact else None)
             with obs.tracer().span("fabric/step", w=w,
                                    n_active=int(n_active.sum()),
                                    shards_active=int(shards_active.sum()),

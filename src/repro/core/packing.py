@@ -317,16 +317,17 @@ def _aligned_words(pt: PackedText, offs: jax.Array, w: int) -> jax.Array:
 
 
 def _spread_to_bytes(chunk: jax.Array, bits: int) -> jax.Array:
-    """Spread 4 right-aligned ``bits``-bit fields of a uint32 lane into the
-    4 big-endian bytes of the lane (classic bit-interleave deposit)."""
+    """Spread 4 right-aligned ``bits``-bit fields of a 32-bit lane (uint32,
+    or int32 inside Pallas kernels) into the 4 big-endian bytes of the
+    lane (classic bit-interleave deposit)."""
     if bits == 8:
         return chunk
     if bits == 4:
-        t = (chunk | (chunk << 8)) & jnp.uint32(0x00FF00FF)
-        return (t | (t << 4)) & jnp.uint32(0x0F0F0F0F)
+        t = (chunk | (chunk << 8)) & 0x00FF00FF
+        return (t | (t << 4)) & 0x0F0F0F0F
     if bits == 2:
-        t = (chunk | (chunk << 12)) & jnp.uint32(0x000F000F)
-        return (t | (t << 6)) & jnp.uint32(0x03030303)
+        t = (chunk | (chunk << 12)) & 0x000F000F
+        return (t | (t << 6)) & 0x03030303
     raise ValueError(f"unsupported dense bits {bits}")
 
 

@@ -192,11 +192,7 @@ def _kernel_impls(use_pallas: bool):
     if use_pallas:
         from repro.kernels.lcp import lcp_pairs as lcp_k
 
-        interp = jax.default_backend() != "tpu"
-        return (
-            kops.range_gather_impl(True),
-            lambda a, b, w: lcp_k(a, b, w, interpret=interp),
-        )
+        return kops.range_gather_impl(True), lcp_k
     from repro.kernels import ref as kref
 
     return kops.range_gather_impl(False), kref.lcp_pairs_ref

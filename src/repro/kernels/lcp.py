@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiles import default_interpret
+from repro.kernels import tiles
 
 
 def _kernel(a_ref, b_ref, lcp_ref, c1_ref, c2_ref, *, w: int, n_words: int, blk: int):
@@ -55,7 +55,6 @@ def lcp_pairs(
     """Row-wise LCP of packed key rows.  a, b: (F, W) int32; returns
     (lcp, c1, c2) int32[F] (fully-equal rows get lcp == w, c1 == c2 == 0).
     ``interpret=None`` compiles on TPU and interprets elsewhere."""
-    interpret = default_interpret(interpret)
     f, n_words = a.shape
     assert b.shape == (f, n_words) and n_words * 4 >= w
     blk = min(blk, f)
@@ -82,7 +81,7 @@ def lcp_pairs(
             jax.ShapeDtypeStruct((fp, 1), jnp.int32),
             jax.ShapeDtypeStruct((fp, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=tiles.default_interpret(interpret),
     )(a, b)
     lcp, c1, c2 = (o[:f, 0] for o in outs)
     return lcp, c1, c2

@@ -49,8 +49,8 @@ from repro.kernels.probe_gather import (
 )
 from repro.kernels.range_gather import range_gather_pack as _gather_pallas
 from repro.kernels.suffix_lcp import suffix_lcp_pairs as _suffix_lcp_pallas
-from repro.kernels.tiles import pick_tile as _pick_tile
-from repro.roofline.analysis import HBM_BW as _HBM_BW
+from repro.kernels import tiles as _tiles
+from repro.roofline.analysis import device_peaks
 
 
 # ---------------------------------------------------------------------------
@@ -67,22 +67,30 @@ _SHAPES_LOCK = threading.Lock()
 
 
 def _record(kernel: str, use_pallas: bool, currency: str, *arrays,
-            tile: int = 0, w: int = 0) -> None:
-    impl = "pallas" if use_pallas else "ref"
+            tile: int = 0, w: int = 0, bits: int | None = None) -> None:
+    if not use_pallas:
+        impl = "ref"
+    else:
+        impl = "interpret" if _tiles.default_interpret(None) else "pallas"
     if obs.trace_enabled():
-        # Roofline prediction on the dispatch marker: every row DMAs a
-        # two-tile halo window, and the compare work is ~w symbol lanes
-        # per row.  Perfetto viewers divide the enclosing span's wall
-        # time by these to read achieved-vs-predicted throughput.
         rows = int(arrays[0].shape[0]) if arrays else 0
-        eff_tile = tile or 2048
-        pred_bytes = rows * 2 * eff_tile * 4
-        obs.tracer().instant(
-            f"kernel/{kernel}/dispatch", kernel=kernel, impl=impl,
-            currency=currency, rows=rows, tile=eff_tile,
-            roofline_pred_bytes=pred_bytes,
-            roofline_pred_flops=rows * max(w, 1),
-            roofline_hbm_us=pred_bytes / _HBM_BW * 1e6)
+        fields = dict(kernel=kernel, impl=impl, currency=currency, rows=rows,
+                      tile=tile)
+        if bits is not None:
+            # Roofline prediction for a paged read of ``w`` symbols of
+            # ``bits`` bits: every row DMAs the 128-word HBM rows holding
+            # its window, and the compare work is ~w symbol lanes per
+            # row.  Perfetto viewers divide the enclosing span's wall time
+            # by these to read achieved-vs-predicted throughput.  Only a
+            # device kind with published peaks gets an HBM time.
+            nw = -(-max(w, 1) * bits // 32)
+            fields.update(roofline_pred_bytes=rows * _tiles.window_bytes(nw),
+                          roofline_pred_flops=rows * max(w, 1))
+            peaks = device_peaks(jax.devices()[0].device_kind)
+            if peaks is not None:
+                fields["roofline_hbm_us"] = (
+                    fields["roofline_pred_bytes"] / peaks["hbm_bw"] * 1e6)
+        obs.tracer().instant(f"kernel/{kernel}/dispatch", **fields)
     if not obs.metrics_enabled():
         return
     m = obs.metrics()
@@ -166,7 +174,7 @@ def _tile(kernel: str, s_text, w: int = 0) -> int:
     else:
         n = int(s_text.shape[0])
         bits = 32
-    return _pick_tile(kernel, n=n, dtype_bits=bits, w_cap=w)
+    return _tiles.pick_tile(kernel, n=n, dtype_bits=bits, w_cap=w)
 
 
 def range_gather_impl(use_pallas: bool):
@@ -177,15 +185,14 @@ def range_gather_impl(use_pallas: bool):
         tile = _tile("range_gather", s_text, w)
         if isinstance(s_text, PackedText):
             _record("range_gather", use_pallas, "packed", offs,
-                    tile=tile, w=w)
+                    tile=tile, w=w, bits=s_text.bits)
             if use_pallas:
-                return _packed_gather_pallas(s_text, offs, w, tile=tile,
-                                             interpret=not _on_tpu())
+                return _packed_gather_pallas(s_text, offs, w, tile=tile)
             return _ref.range_gather_packed_ref(s_text, offs, w)
-        _record("range_gather", use_pallas, "byte", offs, tile=tile, w=w)
+        _record("range_gather", use_pallas, "byte", offs, tile=tile, w=w,
+                bits=8)
         if use_pallas:
-            return _gather_pallas(s_text, offs, w, tile=tile,
-                                  interpret=not _on_tpu())
+            return _gather_pallas(s_text, offs, w, tile=tile)
         return _ref.range_gather_pack_ref(s_text, offs, w)
     return fn
 
@@ -195,10 +202,11 @@ def range_gather_pack(s_text, offs, w: int):
 
 
 def kmer_histogram(s_padded, n: int, k: int, base: int):
-    if _use_pallas():
-        tile = _tile("kmer_histogram", s_padded, k)
-        return _kmer_pallas(s_padded, n, k, base, tile=tile,
-                            interpret=not _on_tpu())
+    use_pallas = _use_pallas()
+    tile = _tile("kmer_histogram", s_padded, k)
+    _record("kmer_histogram", use_pallas, "byte", s_padded, tile=tile, w=k)
+    if use_pallas:
+        return _kmer_pallas(s_padded, n, k, base, tile=tile)
     return _ref.kmer_histogram_ref(s_padded, n, k, base)
 
 
@@ -208,10 +216,10 @@ def range_gather_words_impl(use_pallas: bool):
     only — the word currency has no byte-string form)."""
     def fn(pt: PackedText, offs, w: int):
         tile = _tile("range_gather_words", pt, w)
-        _record("range_gather", use_pallas, "word", offs, tile=tile, w=w)
+        _record("range_gather", use_pallas, "word", offs, tile=tile, w=w,
+                bits=pt.bits)
         if use_pallas:
-            return _words_gather_pallas(pt, offs, w, tile=tile,
-                                        interpret=not _on_tpu())
+            return _words_gather_pallas(pt, offs, w, tile=tile)
         return _ref.range_gather_words_ref(pt, offs, w)
     return fn
 
@@ -226,10 +234,9 @@ def suffix_lcp_pairs(s_text, pos_a, pos_b, w: int):
         if _use_word_compare():
             # word path: first differing dense word + clz, no byte repack
             _record("suffix_lcp", _use_pallas(), "word", pos_a,
-                    tile=tile, w=w)
+                    tile=tile, w=w, bits=s_text.bits)
             if _use_pallas():
-                return _words_lcp_pallas(s_text, pos_a, pos_b, w, tile=tile,
-                                         interpret=not _on_tpu())
+                return _words_lcp_pallas(s_text, pos_a, pos_b, w, tile=tile)
             return _ref.suffix_lcp_words_ref(s_text, pos_a, pos_b, w)
         # byte-key oracle path: two byte-key gathers feed the shared
         # row-LCP — identical to the byte kernel's symbol scan.
@@ -237,16 +244,17 @@ def suffix_lcp_pairs(s_text, pos_a, pos_b, w: int):
         a = gather(s_text, pos_a, w)
         b = gather(s_text, pos_b, w)
         return lcp_pairs(a, b, w)[0]
-    _record("suffix_lcp", _use_pallas(), "byte", pos_a, tile=tile, w=w)
+    _record("suffix_lcp", _use_pallas(), "byte", pos_a, tile=tile, w=w,
+            bits=8)
     if _use_pallas():
-        return _suffix_lcp_pallas(s_text, pos_a, pos_b, w, tile=tile,
-                                  interpret=not _on_tpu())
+        return _suffix_lcp_pallas(s_text, pos_a, pos_b, w, tile=tile)
     return _ref.suffix_lcp_pairs_ref(s_text, pos_a, pos_b, w)
 
 
 def lcp_pairs(a, b, w: int):
+    _record("lcp_pairs", _use_pallas(), "byte", a, w=w)
     if _use_pallas():
-        return _lcp_pallas(a, b, w, interpret=not _on_tpu())
+        return _lcp_pallas(a, b, w)
     return _ref.lcp_pairs_ref(a, b, w)
 
 
@@ -260,18 +268,17 @@ def pattern_probe_impl(use_pallas: bool):
         tile = _tile("pattern_probe", s_text, w)
         if isinstance(s_text, PackedText):
             _record("pattern_probe", use_pallas, "packed", pos, pat_words,
-                    tile=tile, w=w)
+                    tile=tile, w=w, bits=s_text.bits)
             if use_pallas:
                 return _packed_probe_pallas(s_text, pos, pat_words,
-                                            mask_words, tile=tile,
-                                            interpret=not _on_tpu())
+                                            mask_words, tile=tile)
             return _ref.pattern_probe_packed_ref(s_text, pos, pat_words,
                                                  mask_words)
         _record("pattern_probe", use_pallas, "byte", pos, pat_words,
-                tile=tile, w=w)
+                tile=tile, w=w, bits=8)
         if use_pallas:
             return _probe_pallas(s_text, pos, pat_words, mask_words,
-                                 tile=tile, interpret=not _on_tpu())
+                                 tile=tile)
         return _ref.pattern_probe_ref(s_text, pos, pat_words, mask_words)
     return fn
 
@@ -290,11 +297,10 @@ def pattern_probe_words_impl(use_pallas: bool):
         w = pat_dense.shape[1] * (32 // pt.bits)
         tile = _tile("pattern_probe_words", pt, w)
         _record("pattern_probe", use_pallas, "word", pos, pat_dense,
-                tile=tile, w=w)
+                tile=tile, w=w, bits=pt.bits)
         if use_pallas:
             return _words_probe_pallas(pt, pos, pat_dense, mask_dense,
-                                       lengths, lim_p, tile=tile,
-                                       interpret=not _on_tpu())
+                                       lengths, lim_p, tile=tile)
         return _ref.pattern_probe_words_ref(pt, pos, pat_dense, mask_dense,
                                             lengths, lim_p)
     return fn
@@ -316,11 +322,11 @@ def probe_gather_words_impl(use_pallas: bool):
         w = max(pat_dense.shape[1] * (32 // pt.bits), fetch)
         tile = _tile("probe_gather_words", pt, w)
         _record("probe_gather", use_pallas, "word", pos, pat_dense,
-                tile=tile, w=w)
+                tile=tile, w=w, bits=pt.bits)
         if use_pallas:
             return _fused_words_pallas(pt, pos, pat_dense, mask_dense,
                                        lengths, lim_p, fetch=fetch,
-                                       tile=tile, interpret=not _on_tpu())
+                                       tile=tile)
         return _ref.probe_gather_words_ref(pt, pos, pat_dense, mask_dense,
                                            lengths, lim_p, fetch=fetch)
     return fn
@@ -347,12 +353,11 @@ def probe_gather_impl(use_pallas: bool):
             w = max(pat_words.shape[1] * 4, fetch)
             tile = _tile("probe_gather", s_text, w)
             _record("probe_gather", use_pallas, "packed", pos, pat_words,
-                    tile=tile, w=w)
+                    tile=tile, w=w, bits=s_text.bits)
             if use_pallas:
                 return _fused_packed_pallas(s_text, pos, pat_words,
                                             mask_words, fetch=fetch,
-                                            tile=tile,
-                                            interpret=not _on_tpu())
+                                            tile=tile)
             return _ref.probe_gather_packed_ref(s_text, pos, pat_words,
                                                 mask_words, fetch=fetch)
         cmp = pattern_probe_impl(use_pallas)(s_text, pos, pat_words,

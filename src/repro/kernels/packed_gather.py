@@ -1,7 +1,9 @@
 """Pallas TPU kernels over the DENSE k-bit packed string (paper §6.1).
 
-Five kernels share one in-kernel dense-read recipe.  The byte-key family
-(PR 4) repacks dense reads into byte-per-symbol sort keys:
+Five kernels share one in-kernel dense read (:mod:`repro.kernels.tiles`:
+each read's window is DMA'd from HBM, lane-aligned and transposed so that
+word ``j`` of read ``r`` sits at ``[j, r]``).  The byte-key family
+repacks dense reads into byte-per-symbol sort keys:
 
 * :func:`range_gather_packed` — the packed realization of
   :mod:`repro.kernels.range_gather`: gather ``w`` symbols per offset from
@@ -25,14 +27,13 @@ packing argument taken to its end; terminal semantics live in
 * :func:`suffix_lcp_words` — suffix-pair LCP as first-differing-word +
   count-leading-zeros, capped by both terminal limits.
 
-Dense-read recipe: offsets are scalar-prefetched; each grid step DMAs the
-``(2, tile)`` uint32-word window containing the read (a read may straddle
-one tile boundary), slices the ``nw + 1`` words covering the symbols,
-shift-aligns across the sub-word bit offset (``off % syms_per_word``),
-expands the ``bits``-bit fields to one byte per symbol, substitutes the
-virtual terminal for positions ``>= n_real`` (dense storage holds only
-REAL symbols — see :class:`repro.core.packing.PackedText`), and repacks
-big-endian 4-symbols/int32.
+Dense-read recipe: the ``nw + 1`` words covering a read are funnel-shifted
+across the sub-word bit offset (``off % syms_per_word``)
+(:func:`repro.kernels.tiles.aligned_words`); the word family substitutes
+the virtual terminal for positions ``>= n_real`` (dense storage holds
+only REAL symbols — see :class:`repro.core.packing.PackedText`), the
+byte-key family spreads each 4-symbol field to one byte per symbol and
+patches the terminal bytes.
 
 The pure-jnp oracles are :func:`repro.core.packing.gather_pack_dense` /
 ``repro.kernels.ref.pattern_probe_packed_ref``; ``tests/test_packed.py``
@@ -45,48 +46,109 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from jax import lax
 
-from repro.core.packing import PackedText, _sub_word, clz32
-from repro.kernels.tiles import default_interpret as _default_interpret, stage_tiles
+from repro.core.packing import PackedText, _spread_to_bytes, _sub_word, clz32
+from repro.kernels.tiles import (
+    aligned_words,
+    as_i32,
+    paged_call,
+    per_read,
+    srl,
+    stage_rows,
+    n_windows,
+)
+
+SIGN = as_i32(1 << 31)
 
 
-def _dense_read(off, n_real, s_lo_ref, s_hi_ref, *, tile: int, w: int,
-                bits: int, terminal: int):
-    """Read ``w`` byte-expanded symbols at ``off`` from a 2-tile window."""
+def stage_packed(pt: PackedText, nw: int):
+    """The dense words of ``pt`` staged for a read of ``nw`` words."""
+    return stage_rows(lax.bitcast_convert_type(pt.words, jnp.int32),
+                      n_windows(nw))
+
+
+def byte_key_row(ut_ref, off, n_real, k: int, *, bits: int,
+                 terminal: int) -> jax.Array:
+    """Byte sort-key word ``k`` (symbols ``off + 4k .. off + 4k + 3``) of
+    every read as a ``(1, R)`` row, the virtual terminal patched in."""
+    cpw = 32 // bits // 4
+    j, q = divmod(k, cpw)
+    word = aligned_words(ut_ref, off, j, j + 1, bits)
+    if bits < 8:
+        word = srl(word, 32 - 4 * bits * (q + 1)) & ((1 << (4 * bits)) - 1)
+    key = _spread_to_bytes(word, bits)
+    v = jnp.clip(n_real - (off + 4 * k), 0, 4)
+    keep = jnp.where(v > 0, jnp.int32(-1) << (8 * (4 - jnp.maximum(v, 1))), 0)
+    return (key & keep) | (as_i32((terminal & 0xFF) * 0x01010101) & ~keep)
+
+
+def probe_rows(key_rows, pat_ref, mask_ref) -> jax.Array:
+    """Sign of masked byte-key rows vs pattern rows: the first differing
+    word decides, compared unsigned (sign-flipped).  ``(1, R)`` in
+    {-1, 0, +1}."""
+    cmp = None
+    for k, key in enumerate(key_rows):
+        sw = key & mask_ref[k:k + 1, :]
+        pat = pat_ref[k:k + 1, :]
+        lt = (sw ^ SIGN) < (pat ^ SIGN)
+        verdict = jnp.where(sw != pat, jnp.where(lt, -1, 1), 0)
+        cmp = verdict if cmp is None else jnp.where(cmp == 0, verdict, cmp)
+    return cmp
+
+
+def substitute(words: jax.Array, off: jax.Array, n_real, *, bits: int,
+               terminal: int) -> jax.Array:
+    """Keep the first ``v = clip(n_real - start, 0, spw)`` fields of each
+    word (``start`` its first symbol) and substitute
+    :func:`repro.core.packing.sub_code` for the rest."""
     spw = 32 // bits
-    nw = -(-w // spw)
-    word0 = off // spw
-    local = word0 - (word0 // tile) * tile  # word offset within the window
-    flat = jnp.concatenate([s_lo_ref[...], s_hi_ref[...]], axis=1).reshape(2 * tile)
-    u = jax.lax.dynamic_slice(flat, (local,), (nw + 1,)).astype(jnp.uint32)
-    sh = (bits * (off - word0 * spw)).astype(jnp.uint32)
-    hi = u[:-1] << sh
-    # funnel low half: (x >> 1) >> (31 - sh) == x >> (32 - sh) for sh > 0
-    # and 0 at sh == 0, keeping every shift amount in-range select-free
-    lo = (u[1:] >> 1) >> (31 - sh)
-    aligned = hi | lo  # (nw,) each holding spw big-endian symbols
-    shifts = 32 - bits * (jax.lax.iota(jnp.uint32, spw) + 1)
-    sym = (aligned[:, None] >> shifts[None, :]) & jnp.uint32((1 << bits) - 1)
-    sym = sym.reshape(nw * spw)[:w].astype(jnp.int32)
-    past_end = off + jax.lax.iota(jnp.int32, w) >= n_real
-    return jnp.where(past_end, jnp.int32(terminal), sym)
+    starts = off + spw * lax.broadcasted_iota(jnp.int32, words.shape, 0)
+    v = jnp.clip(n_real - starts, 0, spw)
+    keep = jnp.where(v > 0,
+                     jnp.int32(-1) << ((spw - jnp.maximum(v, 1)) * bits), 0)
+    return (words & keep) | (as_i32(_sub_word(bits, terminal)) & ~keep)
 
 
-def _repack_bytes(sym, w: int):
-    grp = sym.reshape(w // 4, 4)
-    # unrolled big-endian pack (pallas kernels cannot capture array consts)
-    return (grp[:, 0] * (1 << 24) + grp[:, 1] * (1 << 16)
-            + grp[:, 2] * (1 << 8) + grp[:, 3])
+def first_diff(a: jax.Array, b: jax.Array, bits: int):
+    """Column-wise first difference of ``(nw, R)`` word blocks:
+    ``(p, aw, bw, sym)`` — the first differing symbol index
+    (``nw * spw`` when equal), the words holding it and its field index
+    inside them."""
+    nw = a.shape[0]
+    spw = 32 // bits
+    x = a ^ b
+    rows = lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    first = jnp.min(jnp.where(x != 0, rows, nw), axis=0, keepdims=True)
+    sel = rows == first
+    pick = lambda v: jnp.sum(jnp.where(sel, v, 0), axis=0, keepdims=True)
+    sym = clz32(pick(x)) // bits
+    p = jnp.where(first < nw, first * spw + sym, nw * spw)
+    return p, pick(a), pick(b), jnp.minimum(sym, spw - 1)
 
 
-def _gather_kernel(offs_ref, nr_ref, s_lo_ref, s_hi_ref, out_ref,
-                   *, tile: int, w: int, bits: int, terminal: int):
-    i = pl.program_id(0)
-    sym = _dense_read(offs_ref[i], nr_ref[0], s_lo_ref, s_hi_ref,
-                      tile=tile, w=w, bits=bits, terminal=terminal)
-    out_ref[0, :] = _repack_bytes(sym, w)
+def word_verdict(sw, pat, pos, cmp_len, lim_p, n_real, *, bits: int):
+    """Word-compare probe verdict (``kernels.ref.probe_words_ref`` rules):
+    a difference below both terminal limits decides by symbol; otherwise
+    the side whose limit comes first is larger."""
+    nw = sw.shape[0]
+    big = nw * (32 // bits)
+    p, aw, bw, sym = first_diff(sw, pat, bits)
+    sh = 32 - bits * (sym + 1)
+    ones = (1 << bits) - 1
+    ca = srl(aw, sh) & ones
+    cb = srl(bw, sh) & ones
+    sym_sign = jnp.where(ca < cb, -1, 1)
+    ls = n_real - pos
+    ls = jnp.where(ls < cmp_len, ls, big)
+    lp = jnp.where(lim_p < cmp_len, lim_p, big)
+    lim_sign = jnp.where(ls < lp, 1, jnp.where(lp < ls, -1, 0))
+    return jnp.where(p < jnp.minimum(ls, lp), sym_sign, lim_sign)
+
+
+# ---------------------------------------------------------------------------
+# Byte-key family
+# ---------------------------------------------------------------------------
 
 
 @functools.partial(jax.jit, static_argnames=("w", "tile", "interpret"))
@@ -104,55 +166,24 @@ def range_gather_packed(
     the ``extra`` contract of :func:`repro.core.packing.pack_text`);
     offs: (F,) int32.  Returns (F, w//4) int32, bit-identical to
     :func:`repro.kernels.range_gather.range_gather_pack` on the
-    terminal-padded byte string.
+    terminal-padded byte string.  ``tile``: reads per grid step.
     """
     assert w % 4 == 0, w
-    spw = pt.syms_per_word
-    nw = -(-w // spw)
-    assert nw + 1 <= tile, (w, pt.bits, tile)
-    f = offs.shape[0]
-    s_rows, _ = stage_tiles(pt.words, tile)
+    nw = -(-w // pt.syms_per_word)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(f,),
-        in_specs=[
-            # the word window may straddle one tile boundary: fetch tiles
-            # r and r+1 as two (1, tile) blocks (halo row exists by staging)
-            pl.BlockSpec((1, tile),
-                         lambda i, offs_ref, nr_ref: ((offs_ref[i] // spw) // tile, 0)),
-            pl.BlockSpec((1, tile),
-                         lambda i, offs_ref, nr_ref: ((offs_ref[i] // spw) // tile + 1, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, w // 4), lambda i, offs_ref, nr_ref: (i, 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_gather_kernel, tile=tile, w=w, bits=pt.bits,
-                          terminal=pt.terminal),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((f, w // 4), jnp.int32),
-        interpret=_default_interpret(interpret),
-    )(offs.astype(jnp.int32), jnp.reshape(pt.n_real, (1,)).astype(jnp.int32),
-      s_rows, s_rows)
+    def body(sc, offs_, uts, _, outs):
+        for k in range(w // 4):
+            outs[0][k:k + 1, :] = byte_key_row(
+                uts[0], offs_[0], sc[0], k, bits=pt.bits, terminal=pt.terminal)
 
+    def call(pt, offs):
+        rows, n_rows = stage_packed(pt, nw)
+        (keys,) = paged_call(body, rows, n_rows, spw=pt.syms_per_word, nw=nw,
+                             starts=[offs], scalars=[pt.n_real],
+                             out_rows=[w // 4], tile=tile, interpret=interpret)
+        return keys.T
 
-def _probe_kernel(pos_ref, nr_ref, s_lo_ref, s_hi_ref, pat_ref, mask_ref,
-                  out_ref, *, tile: int, w: int, bits: int, terminal: int):
-    i = pl.program_id(0)
-    sym = _dense_read(pos_ref[i], nr_ref[0], s_lo_ref, s_hi_ref,
-                      tile=tile, w=w, bits=bits, terminal=terminal)
-    words = _repack_bytes(sym, w)
-    pat = pat_ref[0, :]
-    sw = words & mask_ref[0, :]
-    neq = sw != pat
-    n_words = w // 4
-    iota = jax.lax.iota(jnp.int32, n_words)
-    first = jnp.min(jnp.where(neq, iota, n_words))
-    sel = iota == first
-    sign = jnp.int32(-(1 << 31))
-    a = jnp.sum(jnp.where(sel, sw, 0)) ^ sign
-    b = jnp.sum(jnp.where(sel, pat, 0)) ^ sign
-    out_ref[0, 0] = jnp.where(jnp.any(neq), jnp.where(a < b, -1, 1), 0)
+    return per_read(call, pt, offs)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -173,92 +204,28 @@ def pattern_probe_packed(
     :func:`repro.kernels.pattern_probe.pattern_probe` on the byte string.
     """
     b, n_words = pat_words.shape
-    w = n_words * 4
     assert mask_words.shape == (b, n_words) and pos.shape == (b,)
-    spw = pt.syms_per_word
-    nw = -(-w // spw)
-    assert nw + 1 <= tile, (w, pt.bits, tile)
-    s_rows, _ = stage_tiles(pt.words, tile)
+    nw = -(-(n_words * 4) // pt.syms_per_word)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, tile),
-                         lambda i, pos_ref, nr_ref: ((pos_ref[i] // spw) // tile, 0)),
-            pl.BlockSpec((1, tile),
-                         lambda i, pos_ref, nr_ref: ((pos_ref[i] // spw) // tile + 1, 0)),
-            pl.BlockSpec((1, n_words), lambda i, pos_ref, nr_ref: (i, 0)),
-            pl.BlockSpec((1, n_words), lambda i, pos_ref, nr_ref: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, pos_ref, nr_ref: (i, 0)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_probe_kernel, tile=tile, w=w, bits=pt.bits,
-                          terminal=pt.terminal),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        interpret=_default_interpret(interpret),
-    )(pos.astype(jnp.int32), jnp.reshape(pt.n_real, (1,)).astype(jnp.int32),
-      s_rows, s_rows, pat_words, mask_words)
-    return out[:, 0]
+    def body(sc, offs_, uts, vecs, outs):
+        keys = [byte_key_row(uts[0], offs_[0], sc[0], k, bits=pt.bits,
+                             terminal=pt.terminal) for k in range(n_words)]
+        outs[0][...] = probe_rows(keys, vecs[0], vecs[1])
+
+    def call(pt, pos, pat, mask):
+        rows, n_rows = stage_packed(pt, nw)
+        (cmp,) = paged_call(body, rows, n_rows, spw=pt.syms_per_word, nw=nw,
+                            starts=[pos], vecs=[pat.T, mask.T],
+                            scalars=[pt.n_real], out_rows=[1], tile=tile,
+                            interpret=interpret)
+        return cmp[0]
+
+    return per_read(call, pt, pos, pat_words, mask_words)
 
 
 # ---------------------------------------------------------------------------
-# Word-compare kernels: dense uint32 words are the comparison currency
+# Word-compare family: dense uint32 words are the comparison currency
 # ---------------------------------------------------------------------------
-
-
-def _dense_read_words(off, n_real, s_lo_ref, s_hi_ref, *, tile: int, nw: int,
-                      bits: int, terminal: int):
-    """Read ``nw`` shift-aligned SUBSTITUTED dense words at symbol ``off``
-    from a 2-tile uint32 window (the in-kernel form of
-    :func:`repro.core.packing.gather_words_dense`)."""
-    spw = 32 // bits
-    word0 = off // spw
-    local = word0 - (word0 // tile) * tile
-    flat = jnp.concatenate([s_lo_ref[...], s_hi_ref[...]], axis=1).reshape(2 * tile)
-    u = jax.lax.dynamic_slice(flat, (local,), (nw + 1,)).astype(jnp.uint32)
-    sh = (bits * (off - word0 * spw)).astype(jnp.uint32)
-    hi = u[:-1] << sh
-    lo = (u[1:] >> 1) >> (31 - sh)  # funnel low half, shift always in-range
-    aligned = hi | lo
-    # virtual terminal: keep the first v = clip(n_real - start, 0, spw)
-    # fields of each word, substitute sub_code for the rest
-    starts = off + spw * jax.lax.iota(jnp.int32, nw)
-    v = jnp.clip(n_real - starts, 0, spw)
-    full = jnp.uint32(0xFFFFFFFF)
-    keep = jnp.where(
-        v > 0,
-        full << ((spw - jnp.maximum(v, 1)) * bits).astype(jnp.uint32),
-        jnp.uint32(0))
-    sub_w = jnp.uint32(_sub_word(bits, terminal))
-    return (aligned & keep) | (sub_w & ~keep)
-
-
-def _first_diff(a, b, nw: int, bits: int):
-    """(p, aw, bw): first differing symbol index of two word vectors plus
-    the words holding it (p == nw * spw when equal)."""
-    spw = 32 // bits
-    x = a ^ b
-    neq = x != 0
-    iota = jax.lax.iota(jnp.int32, nw)
-    first = jnp.min(jnp.where(neq, iota, nw))
-    sel = iota == first
-    xw = jnp.sum(jnp.where(sel, x, jnp.uint32(0)))
-    aw = jnp.sum(jnp.where(sel, a, jnp.uint32(0)))
-    bw = jnp.sum(jnp.where(sel, b, jnp.uint32(0)))
-    sym = clz32(xw) // bits
-    p = jnp.where(jnp.any(neq), first * spw + sym, nw * spw)
-    return p, aw, bw, jnp.minimum(sym, spw - 1)
-
-
-def _words_gather_kernel(offs_ref, nr_ref, s_lo_ref, s_hi_ref, out_ref,
-                         *, tile: int, nw: int, bits: int, terminal: int):
-    i = pl.program_id(0)
-    words = _dense_read_words(offs_ref[i], nr_ref[0], s_lo_ref, s_hi_ref,
-                              tile=tile, nw=nw, bits=bits, terminal=terminal)
-    out_ref[0, :] = words.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("w", "tile", "interpret"))
@@ -275,60 +242,21 @@ def range_gather_words(
     spread to bytes.  Returns (F, nw) uint32, bit-identical to
     :func:`repro.core.packing.gather_words_dense`.
     """
-    spw = pt.syms_per_word
-    nw = -(-w // spw)
-    assert nw + 1 <= tile, (w, pt.bits, tile)
-    f = offs.shape[0]
-    s_rows, _ = stage_tiles(pt.words, tile)
+    nw = -(-w // pt.syms_per_word)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(f,),
-        in_specs=[
-            pl.BlockSpec((1, tile),
-                         lambda i, offs_ref, nr_ref: ((offs_ref[i] // spw) // tile, 0)),
-            pl.BlockSpec((1, tile),
-                         lambda i, offs_ref, nr_ref: ((offs_ref[i] // spw) // tile + 1, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, nw), lambda i, offs_ref, nr_ref: (i, 0)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_words_gather_kernel, tile=tile, nw=nw, bits=pt.bits,
-                          terminal=pt.terminal),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((f, nw), jnp.int32),
-        interpret=_default_interpret(interpret),
-    )(offs.astype(jnp.int32), jnp.reshape(pt.n_real, (1,)).astype(jnp.int32),
-      s_rows, s_rows)
-    return jax.lax.bitcast_convert_type(out, jnp.uint32)
+    def body(sc, offs_, uts, _, outs):
+        words = aligned_words(uts[0], offs_[0], 0, nw, pt.bits)
+        outs[0][...] = substitute(words, offs_[0], sc[0], bits=pt.bits,
+                                  terminal=pt.terminal)
 
+    def call(pt, offs):
+        rows, n_rows = stage_packed(pt, nw)
+        (words,) = paged_call(body, rows, n_rows, spw=pt.syms_per_word,
+                              nw=nw, starts=[offs], scalars=[pt.n_real],
+                              out_rows=[nw], tile=tile, interpret=interpret)
+        return lax.bitcast_convert_type(words.T, jnp.uint32)
 
-def _words_probe_kernel(pos_ref, len_ref, limp_ref, nr_ref, s_lo_ref, s_hi_ref,
-                        pat_ref, mask_ref, out_ref,
-                        *, tile: int, nw: int, bits: int, terminal: int):
-    i = pl.program_id(0)
-    spw = 32 // bits
-    big = nw * spw
-    pos = pos_ref[i]
-    sw = _dense_read_words(pos, nr_ref[0], s_lo_ref, s_hi_ref,
-                           tile=tile, nw=nw, bits=bits, terminal=terminal)
-    mask = jax.lax.bitcast_convert_type(mask_ref[0, :], jnp.uint32)
-    pat = jax.lax.bitcast_convert_type(pat_ref[0, :], jnp.uint32)
-    p, aw, bw, sym = _first_diff(sw & mask, pat, nw, bits)
-    sh = (32 - bits * (sym + 1)).astype(jnp.uint32)
-    ones = jnp.uint32((1 << bits) - 1)
-    ca = ((aw >> sh) & ones).astype(jnp.int32)
-    cb = ((bw >> sh) & ones).astype(jnp.int32)
-    sym_sign = jnp.where(ca < cb, -1, 1)
-    # terminal-limit rules (core.packing module docstring): limits at or
-    # past the compare length saturate out of the comparison
-    cmp_len = len_ref[i]
-    ls = nr_ref[0] - pos
-    lp = limp_ref[i]
-    ls = jnp.where(ls < cmp_len, ls, big)
-    lp = jnp.where(lp < cmp_len, lp, big)
-    lim_sign = jnp.where(ls < lp, 1, jnp.where(lp < ls, -1, 0))
-    out_ref[0, 0] = jnp.where(p < jnp.minimum(ls, lp), sym_sign, lim_sign)
+    return per_read(call, pt, offs)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -355,60 +283,30 @@ def pattern_probe_words(
     :func:`repro.kernels.ref.pattern_probe_words_ref`).
     """
     b, nw = pat_dense.shape
-    spw = pt.syms_per_word
     assert mask_dense.shape == (b, nw) and pos.shape == (b,)
-    assert nw + 1 <= tile, (nw, pt.bits, tile)
     if lim_p is None:
         lim_p = lengths
-    s_rows, _ = stage_tiles(pt.words, tile)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, tile),
-                         lambda i, pos_ref, len_ref, limp_ref, nr_ref:
-                         ((pos_ref[i] // spw) // tile, 0)),
-            pl.BlockSpec((1, tile),
-                         lambda i, pos_ref, len_ref, limp_ref, nr_ref:
-                         ((pos_ref[i] // spw) // tile + 1, 0)),
-            pl.BlockSpec((1, nw),
-                         lambda i, pos_ref, len_ref, limp_ref, nr_ref: (i, 0)),
-            pl.BlockSpec((1, nw),
-                         lambda i, pos_ref, len_ref, limp_ref, nr_ref: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1), lambda i, pos_ref, len_ref, limp_ref, nr_ref: (i, 0)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_words_probe_kernel, tile=tile, nw=nw, bits=pt.bits,
-                          terminal=pt.terminal),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        interpret=_default_interpret(interpret),
-    )(pos.astype(jnp.int32), lengths.astype(jnp.int32),
-      lim_p.astype(jnp.int32),
-      jnp.reshape(pt.n_real, (1,)).astype(jnp.int32),
-      s_rows, s_rows,
-      jax.lax.bitcast_convert_type(pat_dense, jnp.int32),
-      jax.lax.bitcast_convert_type(mask_dense, jnp.int32))
-    return out[:, 0]
+    def body(sc, offs_, uts, vecs, outs):
+        pat, mask, cmp_len, lp = (r[...] for r in vecs)
+        sw = substitute(aligned_words(uts[0], offs_[0], 0, nw, pt.bits),
+                        offs_[0], sc[0], bits=pt.bits, terminal=pt.terminal)
+        outs[0][...] = word_verdict(sw & mask, pat, offs_[0], cmp_len, lp,
+                                    sc[0], bits=pt.bits)
 
+    def call(pt, pos, pat, mask, lengths, lim_p):
+        rows, n_rows = stage_packed(pt, nw)
+        i32 = lambda x: lax.bitcast_convert_type(x, jnp.int32).T
+        (cmp,) = paged_call(body, rows, n_rows, spw=pt.syms_per_word, nw=nw,
+                            starts=[pos],
+                            vecs=[i32(pat), i32(mask), lengths[None, :],
+                                  lim_p[None, :]],
+                            scalars=[pt.n_real], out_rows=[1], tile=tile,
+                            interpret=interpret)
+        return cmp[0]
 
-def _words_lcp_kernel(pa_ref, pb_ref, nr_ref, a_lo_ref, a_hi_ref,
-                      b_lo_ref, b_hi_ref, out_ref,
-                      *, tile: int, nw: int, w: int, bits: int, terminal: int):
-    i = pl.program_id(0)
-    oa = pa_ref[i]
-    ob = pb_ref[i]
-    a = _dense_read_words(oa, nr_ref[0], a_lo_ref, a_hi_ref,
-                          tile=tile, nw=nw, bits=bits, terminal=terminal)
-    b = _dense_read_words(ob, nr_ref[0], b_lo_ref, b_hi_ref,
-                          tile=tile, nw=nw, bits=bits, terminal=terminal)
-    p, _, _, _ = _first_diff(a, b, nw, bits)
-    la = jnp.clip(nr_ref[0] - oa, 0, w)
-    lb = jnp.clip(nr_ref[0] - ob, 0, w)
-    out_ref[0, 0] = jnp.minimum(jnp.minimum(jnp.minimum(p, la), lb), w)
+    return per_read(call, pt, pos, pat_dense, mask_dense,
+                    lengths.astype(jnp.int32), lim_p.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("w", "tile", "interpret"))
@@ -428,35 +326,25 @@ def suffix_lcp_words(
     equal to the byte symbol scan for distinct suffix pairs (oracle:
     :func:`repro.kernels.ref.suffix_lcp_words_ref`).
     """
-    spw = pt.syms_per_word
-    nw = -(-w // spw)
-    assert nw + 1 <= tile, (w, pt.bits, tile)
-    b = pos_a.shape[0]
-    assert pos_b.shape == (b,)
-    s_rows, _ = stage_tiles(pt.words, tile)
+    nw = -(-w // pt.syms_per_word)
+    assert pos_b.shape == pos_a.shape
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, tile),
-                         lambda i, pa, pb, nr: ((pa[i] // spw) // tile, 0)),
-            pl.BlockSpec((1, tile),
-                         lambda i, pa, pb, nr: ((pa[i] // spw) // tile + 1, 0)),
-            pl.BlockSpec((1, tile),
-                         lambda i, pa, pb, nr: ((pb[i] // spw) // tile, 0)),
-            pl.BlockSpec((1, tile),
-                         lambda i, pa, pb, nr: ((pb[i] // spw) // tile + 1, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, pa, pb, nr: (i, 0)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_words_lcp_kernel, tile=tile, nw=nw, w=w,
-                          bits=pt.bits, terminal=pt.terminal),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        interpret=_default_interpret(interpret),
-    )(pos_a.astype(jnp.int32), pos_b.astype(jnp.int32),
-      jnp.reshape(pt.n_real, (1,)).astype(jnp.int32),
-      s_rows, s_rows, s_rows, s_rows)
-    return out[:, 0]
+    def body(sc, offs_, uts, _, outs):
+        oa, ob = offs_
+        nr = sc[0]
+        a, b = (substitute(aligned_words(ut, o, 0, nw, pt.bits), o, nr,
+                           bits=pt.bits, terminal=pt.terminal)
+                for ut, o in zip(uts, offs_))
+        p = first_diff(a, b, pt.bits)[0]
+        la = jnp.clip(nr - oa, 0, w)
+        lb = jnp.clip(nr - ob, 0, w)
+        outs[0][...] = jnp.minimum(jnp.minimum(jnp.minimum(p, la), lb), w)
+
+    def call(pt, pos_a, pos_b):
+        rows, n_rows = stage_packed(pt, nw)
+        (lcp,) = paged_call(body, rows, n_rows, spw=pt.syms_per_word, nw=nw,
+                            starts=[pos_a, pos_b], scalars=[pt.n_real],
+                            out_rows=[1], tile=tile, interpret=interpret)
+        return lcp[0]
+
+    return per_read(call, pt, pos_a, pos_b)

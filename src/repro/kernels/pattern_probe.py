@@ -8,12 +8,12 @@ inner step of that search is this kernel: for each probe position, gather
 mask past the pattern length, and emit the sign of the comparison with the
 pre-packed pattern row.
 
-Layout mirrors :mod:`repro.kernels.range_gather`: probe positions are
-scalar-prefetched (paged-gather block-table style), each grid step DMAs the
-``(2, tile)`` HBM window containing the read plus the pattern/mask rows,
-and writes one ``(1, 1)`` comparison verdict.  Comparisons run on the
-sign-flipped words so signed int32 order equals unsigned (lexicographic)
-order — required for the byte alphabet whose codes reach the top bit.
+Layout mirrors :mod:`repro.kernels.range_gather`: each probe's window is
+DMA'd from the staged 8-bit words in HBM, the pattern/mask rows arrive as
+lane-dense column blocks, and each read writes one comparison verdict.
+Comparisons run on the sign-flipped words so signed int32 order equals
+unsigned (lexicographic) order — required for the byte alphabet whose
+codes reach the top bit.
 """
 
 from __future__ import annotations
@@ -21,36 +21,10 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiles import default_interpret, stage_tiles
-
-
-def _kernel(pos_ref, s_lo_ref, s_hi_ref, pat_ref, mask_ref, out_ref,
-            *, tile: int, w: int):
-    i = pl.program_id(0)
-    off = pos_ref[i]
-    local = off - (off // tile) * tile  # offset within the 2-tile window
-    flat = jnp.concatenate([s_lo_ref[...], s_hi_ref[...]], axis=1).reshape(2 * tile)
-    sym = jax.lax.dynamic_slice(flat, (local,), (w,))
-    grp = sym.reshape(w // 4, 4).astype(jnp.int32)
-    # unrolled big-endian pack (pallas kernels cannot capture array consts)
-    words = (grp[:, 0] * (1 << 24) + grp[:, 1] * (1 << 16)
-             + grp[:, 2] * (1 << 8) + grp[:, 3])
-    pat = pat_ref[0, :]
-    sw = words & mask_ref[0, :]
-    neq = sw != pat
-    n_words = w // 4
-    iota = jax.lax.iota(jnp.int32, n_words)
-    first = jnp.min(jnp.where(neq, iota, n_words))
-    sel = iota == first
-    sign = jnp.int32(-(1 << 31))
-    a = jnp.sum(jnp.where(sel, sw, 0)) ^ sign
-    b = jnp.sum(jnp.where(sel, pat, 0)) ^ sign
-    cmp = jnp.where(jnp.any(neq), jnp.where(a < b, -1, 1), 0)
-    out_ref[0, 0] = cmp
+from repro.kernels.packed_gather import probe_rows
+from repro.kernels.range_gather import stage_bytes
+from repro.kernels.tiles import aligned_words, paged_call, per_read
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -70,30 +44,19 @@ def pattern_probe(
     Returns int32[B] in {-1, 0, +1} (0 == suffix starts with pattern).
     ``interpret=None`` compiles on TPU and interprets elsewhere.
     """
-    interpret = default_interpret(interpret)
     b, n_words = pat_words.shape
-    w = n_words * 4
     assert mask_words.shape == (b, n_words) and pos.shape == (b,)
-    tile = max(tile, w)  # long patterns (to_device(max_pattern_len=...)) grow the window
-    s_rows, _ = stage_tiles(s_padded, tile)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b,),
-        in_specs=[
-            # the read window may straddle one tile boundary: fetch tiles
-            # r and r+1 as two (1, tile) blocks (halo row exists by padding)
-            pl.BlockSpec((1, tile), lambda i, pos_ref: (pos_ref[i] // tile, 0)),
-            pl.BlockSpec((1, tile), lambda i, pos_ref: (pos_ref[i] // tile + 1, 0)),
-            pl.BlockSpec((1, n_words), lambda i, pos_ref: (i, 0)),
-            pl.BlockSpec((1, n_words), lambda i, pos_ref: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, pos_ref: (i, 0)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_kernel, tile=tile, w=w),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        interpret=interpret,
-    )(pos.astype(jnp.int32), s_rows, s_rows, pat_words, mask_words)
-    return out[:, 0]
+    def body(sc, offs_, uts, vecs, outs):
+        keys = [aligned_words(uts[0], offs_[0], k, k + 1, 8)
+                for k in range(n_words)]
+        outs[0][...] = probe_rows(keys, vecs[0], vecs[1])
+
+    def call(s, pos, pat, mask):
+        rows, n_rows = stage_bytes(s, n_words)
+        (cmp,) = paged_call(body, rows, n_rows, spw=4, nw=n_words,
+                            starts=[pos], vecs=[pat.T, mask.T], out_rows=[1],
+                            tile=tile, interpret=interpret)
+        return cmp[0]
+
+    return per_read(call, s_padded, pos, pat_words, mask_words)

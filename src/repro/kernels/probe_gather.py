@@ -31,49 +31,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from jax import lax
 
 from repro.core.packing import PackedText
 from repro.kernels.packed_gather import (
-    _dense_read,
-    _dense_read_words,
-    _first_diff,
-    _repack_bytes,
+    byte_key_row,
+    probe_rows,
+    stage_packed,
+    substitute,
+    word_verdict,
 )
-from repro.kernels.tiles import default_interpret as _default_interpret, stage_tiles
-
-
-def _fused_words_kernel(pos_ref, len_ref, limp_ref, nr_ref, s_lo_ref, s_hi_ref,
-                        pat_ref, mask_ref, cmp_ref, win_ref,
-                        *, tile: int, nw_pat: int, nw_out: int, bits: int,
-                        terminal: int):
-    i = pl.program_id(0)
-    spw = 32 // bits
-    nw_rd = max(nw_pat, nw_out)
-    pos = pos_ref[i]
-    sw = _dense_read_words(pos, nr_ref[0], s_lo_ref, s_hi_ref,
-                           tile=tile, nw=nw_rd, bits=bits, terminal=terminal)
-    # gather half: the first nw_out substituted words ARE what
-    # range_gather_words emits (per-word substitution is independent)
-    win_ref[0, :] = sw[:nw_out].astype(jnp.int32)
-    # probe half: identical to packed_gather._words_probe_kernel
-    big = nw_pat * spw
-    mask = jax.lax.bitcast_convert_type(mask_ref[0, :], jnp.uint32)
-    pat = jax.lax.bitcast_convert_type(pat_ref[0, :], jnp.uint32)
-    p, aw, bw, sym = _first_diff(sw[:nw_pat] & mask, pat, nw_pat, bits)
-    sh = (32 - bits * (sym + 1)).astype(jnp.uint32)
-    ones = jnp.uint32((1 << bits) - 1)
-    ca = ((aw >> sh) & ones).astype(jnp.int32)
-    cb = ((bw >> sh) & ones).astype(jnp.int32)
-    sym_sign = jnp.where(ca < cb, -1, 1)
-    cmp_len = len_ref[i]
-    ls = nr_ref[0] - pos
-    lp = limp_ref[i]
-    ls = jnp.where(ls < cmp_len, ls, big)
-    lp = jnp.where(lp < cmp_len, lp, big)
-    lim_sign = jnp.where(ls < lp, 1, jnp.where(lp < ls, -1, 0))
-    cmp_ref[0, 0] = jnp.where(p < jnp.minimum(ls, lp), sym_sign, lim_sign)
+from repro.kernels.tiles import aligned_words, paged_call, per_read
 
 
 @functools.partial(jax.jit, static_argnames=("fetch", "tile", "interpret"))
@@ -98,76 +66,36 @@ def probe_gather_words(
     fetch)`` (oracle: :func:`repro.kernels.ref.probe_gather_words_ref`).
     """
     b, nw_pat = pat_dense.shape
-    spw = pt.syms_per_word
-    nw_out = -(-fetch // spw)
+    nw_out = -(-fetch // pt.syms_per_word)
     nw_rd = max(nw_pat, nw_out)
     assert mask_dense.shape == (b, nw_pat) and pos.shape == (b,)
-    assert nw_rd + 1 <= tile, (nw_rd, pt.bits, tile)
     if lim_p is None:
         lim_p = lengths
-    s_rows, _ = stage_tiles(pt.words, tile)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, tile),
-                         lambda i, pos_ref, len_ref, limp_ref, nr_ref:
-                         ((pos_ref[i] // spw) // tile, 0)),
-            pl.BlockSpec((1, tile),
-                         lambda i, pos_ref, len_ref, limp_ref, nr_ref:
-                         ((pos_ref[i] // spw) // tile + 1, 0)),
-            pl.BlockSpec((1, nw_pat),
-                         lambda i, pos_ref, len_ref, limp_ref, nr_ref: (i, 0)),
-            pl.BlockSpec((1, nw_pat),
-                         lambda i, pos_ref, len_ref, limp_ref, nr_ref: (i, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, 1),
-                         lambda i, pos_ref, len_ref, limp_ref, nr_ref: (i, 0)),
-            pl.BlockSpec((1, nw_out),
-                         lambda i, pos_ref, len_ref, limp_ref, nr_ref: (i, 0)),
-        ),
-    )
-    cmp, win = pl.pallas_call(
-        functools.partial(_fused_words_kernel, tile=tile, nw_pat=nw_pat,
-                          nw_out=nw_out, bits=pt.bits, terminal=pt.terminal),
-        grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((b, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((b, nw_out), jnp.int32)),
-        interpret=_default_interpret(interpret),
-    )(pos.astype(jnp.int32), lengths.astype(jnp.int32),
-      lim_p.astype(jnp.int32),
-      jnp.reshape(pt.n_real, (1,)).astype(jnp.int32),
-      s_rows, s_rows,
-      jax.lax.bitcast_convert_type(pat_dense, jnp.int32),
-      jax.lax.bitcast_convert_type(mask_dense, jnp.int32))
-    return cmp[:, 0], jax.lax.bitcast_convert_type(win, jnp.uint32)
+    def body(sc, offs_, uts, vecs, outs):
+        pat, mask, cmp_len, lp = (r[...] for r in vecs)
+        sw = substitute(aligned_words(uts[0], offs_[0], 0, nw_rd, pt.bits),
+                        offs_[0], sc[0], bits=pt.bits, terminal=pt.terminal)
+        # gather half: the first nw_out substituted words ARE what
+        # range_gather_words emits (per-word substitution is independent)
+        outs[1][...] = sw[:nw_out]
+        # probe half: identical to packed_gather.pattern_probe_words
+        outs[0][...] = word_verdict(sw[:nw_pat] & mask, pat, offs_[0],
+                                    cmp_len, lp, sc[0], bits=pt.bits)
 
+    def call(pt, pos, pat, mask, lengths, lim_p):
+        rows, n_rows = stage_packed(pt, nw_rd)
+        i32 = lambda x: lax.bitcast_convert_type(x, jnp.int32).T
+        cmp, win = paged_call(body, rows, n_rows, spw=pt.syms_per_word,
+                              nw=nw_rd, starts=[pos],
+                              vecs=[i32(pat), i32(mask), lengths[None, :],
+                                    lim_p[None, :]],
+                              scalars=[pt.n_real], out_rows=[1, nw_out],
+                              tile=tile, interpret=interpret)
+        return cmp[0], lax.bitcast_convert_type(win.T, jnp.uint32)
 
-def _fused_packed_kernel(pos_ref, nr_ref, s_lo_ref, s_hi_ref, pat_ref,
-                         mask_ref, cmp_ref, win_ref,
-                         *, tile: int, w_pat: int, w_out: int, bits: int,
-                         terminal: int):
-    i = pl.program_id(0)
-    w_rd = max(w_pat, w_out)
-    sym = _dense_read(pos_ref[i], nr_ref[0], s_lo_ref, s_hi_ref,
-                      tile=tile, w=w_rd, bits=bits, terminal=terminal)
-    words = _repack_bytes(sym, w_rd)
-    # gather half: first w_out // 4 byte-key words == range_gather_packed
-    win_ref[0, :] = words[: w_out // 4]
-    # probe half: identical to packed_gather._probe_kernel
-    n_words = w_pat // 4
-    pat = pat_ref[0, :]
-    sw = words[:n_words] & mask_ref[0, :]
-    neq = sw != pat
-    iota = jax.lax.iota(jnp.int32, n_words)
-    first = jnp.min(jnp.where(neq, iota, n_words))
-    sel = iota == first
-    sign = jnp.int32(-(1 << 31))
-    a = jnp.sum(jnp.where(sel, sw, 0)) ^ sign
-    b = jnp.sum(jnp.where(sel, pat, 0)) ^ sign
-    cmp_ref[0, 0] = jnp.where(jnp.any(neq), jnp.where(a < b, -1, 1), 0)
+    return per_read(call, pt, pos, pat_dense, mask_dense,
+                    lengths.astype(jnp.int32), lim_p.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("fetch", "tile", "interpret"))
@@ -191,36 +119,23 @@ def probe_gather_packed(
     """
     assert fetch % 4 == 0, fetch
     b, n_words = pat_words.shape
-    w_pat = n_words * 4
-    spw = pt.syms_per_word
-    nw_rd = -(-max(w_pat, fetch) // spw)
     assert mask_words.shape == (b, n_words) and pos.shape == (b,)
-    assert nw_rd + 1 <= tile, (nw_rd, pt.bits, tile)
-    s_rows, _ = stage_tiles(pt.words, tile)
+    n_keys = max(n_words, fetch // 4)
+    nw_rd = -(-(n_keys * 4) // pt.syms_per_word)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, tile),
-                         lambda i, pos_ref, nr_ref: ((pos_ref[i] // spw) // tile, 0)),
-            pl.BlockSpec((1, tile),
-                         lambda i, pos_ref, nr_ref: ((pos_ref[i] // spw) // tile + 1, 0)),
-            pl.BlockSpec((1, n_words), lambda i, pos_ref, nr_ref: (i, 0)),
-            pl.BlockSpec((1, n_words), lambda i, pos_ref, nr_ref: (i, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, 1), lambda i, pos_ref, nr_ref: (i, 0)),
-            pl.BlockSpec((1, fetch // 4), lambda i, pos_ref, nr_ref: (i, 0)),
-        ),
-    )
-    cmp, win = pl.pallas_call(
-        functools.partial(_fused_packed_kernel, tile=tile, w_pat=w_pat,
-                          w_out=fetch, bits=pt.bits, terminal=pt.terminal),
-        grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((b, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((b, fetch // 4), jnp.int32)),
-        interpret=_default_interpret(interpret),
-    )(pos.astype(jnp.int32), jnp.reshape(pt.n_real, (1,)).astype(jnp.int32),
-      s_rows, s_rows, pat_words, mask_words)
-    return cmp[:, 0], win
+    def body(sc, offs_, uts, vecs, outs):
+        keys = [byte_key_row(uts[0], offs_[0], sc[0], k, bits=pt.bits,
+                             terminal=pt.terminal) for k in range(n_keys)]
+        for k in range(fetch // 4):  # gather half == range_gather_packed
+            outs[1][k:k + 1, :] = keys[k]
+        outs[0][...] = probe_rows(keys[:n_words], vecs[0], vecs[1])
+
+    def call(pt, pos, pat, mask):
+        rows, n_rows = stage_packed(pt, nw_rd)
+        cmp, win = paged_call(body, rows, n_rows, spw=pt.syms_per_word,
+                              nw=nw_rd, starts=[pos], vecs=[pat.T, mask.T],
+                              scalars=[pt.n_real], out_rows=[1, fetch // 4],
+                              tile=tile, interpret=interpret)
+        return cmp[0], win.T
+
+    return per_read(call, pt, pos, pat_words, mask_words)

@@ -2,16 +2,15 @@
 
 This is the TPU realization of the paper's "fill R by scanning S" step
 (SubTreePrepare lines 9-12).  On disk the paper streams S sequentially; in
-HBM the natural analogue is a *paged gather*: the per-leaf offset array is
-scalar-prefetched (the same pattern as paged-attention block tables), the
-``index_map`` selects the HBM tile containing each read, and the kernel
-packs ``w`` symbols into big-endian int32 words in VMEM so that integer
-comparisons equal lexicographic symbol comparisons.
+HBM the natural analogue is a *paged gather*: S stays in HBM, each read's
+window of 128-lane rows is DMA'd into VMEM by hand (the paged-attention
+pattern, :mod:`repro.kernels.tiles`), and ``w`` symbols come out packed
+big-endian 4 per int32 so that integer comparisons equal lexicographic
+symbol comparisons.
 
-Tiling: S is reshaped to ``(n_tiles, tile)``; each grid step DMAs a
-``(2, tile)`` window (the read may straddle one tile boundary; ``w <=
-tile`` is enforced) and writes one ``(1, w//4)`` output row.  VMEM per
-step = ``2*tile*4 + w`` bytes — tile=2048 keeps it ~16KB, far under VMEM.
+S is staged as those very words — 4 byte codes per int32
+(:func:`repro.kernels.tiles.pack_bytes`) — so the packed sort key of a
+read is the staged word stream funnel-shifted to the read's offset.
 """
 
 from __future__ import annotations
@@ -19,25 +18,20 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import PACK_WEIGHTS
-from repro.kernels.tiles import default_interpret, stage_tiles
+from repro.kernels.tiles import (
+    aligned_words,
+    n_windows,
+    pack_bytes,
+    paged_call,
+    per_read,
+    stage_rows,
+)
 
 
-def _kernel(offs_ref, s_lo_ref, s_hi_ref, out_ref, *, tile: int, w: int):
-    i = pl.program_id(0)
-    off = offs_ref[i]
-    local = off - (off // tile) * tile  # offset within the 2-tile window
-    flat = jnp.concatenate([s_lo_ref[...], s_hi_ref[...]], axis=1).reshape(2 * tile)
-    sym = jax.lax.dynamic_slice(flat, (local,), (w,))
-    grp = sym.reshape(w // 4, 4).astype(jnp.int32)
-    # unrolled big-endian pack (pallas kernels cannot capture array consts)
-    words = (grp[:, 0] * (1 << 24) + grp[:, 1] * (1 << 16)
-             + grp[:, 2] * (1 << 8) + grp[:, 3])
-    out_ref[0, :] = words
+def stage_bytes(s_padded: jax.Array, nw: int):
+    """The byte string staged as 8-bit words for a read of ``nw`` words."""
+    return stage_rows(pack_bytes(s_padded), n_windows(nw))
 
 
 @functools.partial(jax.jit, static_argnames=("w", "tile", "interpret"))
@@ -52,27 +46,19 @@ def range_gather_pack(
     """Gather ``w`` symbols per offset from S (terminal-padded) and pack.
 
     s_padded: (n,) integer codes;  offs: (F,) int32;  returns (F, w//4) int32.
-    ``interpret=None`` compiles on TPU and interprets elsewhere.
+    ``tile``: reads per grid step.  ``interpret=None`` compiles on TPU and
+    interprets elsewhere.
     """
-    interpret = default_interpret(interpret)
-    assert w % 4 == 0 and w <= tile, (w, tile)
-    f = offs.shape[0]
-    s_rows, _ = stage_tiles(s_padded, tile)
+    assert w % 4 == 0, w
+    nw = w // 4
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(f,),
-        in_specs=[
-            # the read window may straddle one tile boundary: fetch tiles
-            # r and r+1 as two (1, tile) blocks (halo row exists by padding)
-            pl.BlockSpec((1, tile), lambda i, offs_ref: (offs_ref[i] // tile, 0)),
-            pl.BlockSpec((1, tile), lambda i, offs_ref: (offs_ref[i] // tile + 1, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, w // 4), lambda i, offs_ref: (i, 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, tile=tile, w=w),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((f, w // 4), jnp.int32),
-        interpret=interpret,
-    )(offs.astype(jnp.int32), s_rows, s_rows)
+    def body(sc, offs_, uts, _, outs):
+        outs[0][...] = aligned_words(uts[0], offs_[0], 0, nw, 8)
+
+    def call(s, offs):
+        rows, n_rows = stage_bytes(s, nw)
+        (keys,) = paged_call(body, rows, n_rows, spw=4, nw=nw, starts=[offs],
+                             out_rows=[nw], tile=tile, interpret=interpret)
+        return keys.T
+
+    return per_read(call, s_padded, offs)

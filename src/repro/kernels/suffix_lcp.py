@@ -8,12 +8,11 @@ prefix-free vertical-partition prefixes, so their LCP is strictly less than
 the shorter prefix length: a single bounded-width comparison suffices, no
 iterative deepening.
 
-Layout mirrors :mod:`repro.kernels.pattern_probe`: both position arrays are
-scalar-prefetched, each grid step DMAs the two ``(2, tile)`` HBM windows
-containing the reads (a read may straddle one tile boundary) and writes one
-``(1, 1)`` LCP value.  The kernel compares raw symbols (an iota-min over
-the first unequal position) — symbol equality needs no packing, and the
-result is identical to the packed-word reference oracle.
+Layout mirrors :mod:`repro.kernels.pattern_probe`: both suffixes' windows
+are DMA'd from the staged 8-bit words in HBM and each pair writes one LCP
+value.  The first differing packed word and its count of leading zero
+bits locate the first unequal symbol — identical to the packed-word
+reference oracle.
 """
 
 from __future__ import annotations
@@ -22,24 +21,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiles import default_interpret, stage_tiles
-
-
-def _kernel(pa_ref, pb_ref, a_lo_ref, a_hi_ref, b_lo_ref, b_hi_ref, out_ref,
-            *, tile: int, w: int):
-    i = pl.program_id(0)
-    oa = pa_ref[i]
-    ob = pb_ref[i]
-    flat_a = jnp.concatenate([a_lo_ref[...], a_hi_ref[...]], axis=1).reshape(2 * tile)
-    flat_b = jnp.concatenate([b_lo_ref[...], b_hi_ref[...]], axis=1).reshape(2 * tile)
-    sym_a = jax.lax.dynamic_slice(flat_a, (oa - (oa // tile) * tile,), (w,))
-    sym_b = jax.lax.dynamic_slice(flat_b, (ob - (ob // tile) * tile,), (w,))
-    neq = sym_a != sym_b
-    iota = jax.lax.iota(jnp.int32, w)
-    out_ref[0, 0] = jnp.min(jnp.where(neq, iota, w))
+from repro.kernels.packed_gather import first_diff
+from repro.kernels.range_gather import stage_bytes
+from repro.kernels.tiles import aligned_words, paged_call, per_read
 
 
 @functools.partial(jax.jit, static_argnames=("w", "tile", "interpret"))
@@ -59,29 +44,20 @@ def suffix_lcp_pairs(
     capped at ``w`` (pairs equal through ``w`` symbols report exactly ``w``).
     ``interpret=None`` compiles on TPU and interprets elsewhere.
     """
-    interpret = default_interpret(interpret)
     b = pos_a.shape[0]
     assert pos_b.shape == (b,)
     assert w % 4 == 0
-    tile = max(tile, w)
-    s_rows, _ = stage_tiles(s_padded, tile)
+    nw = w // 4
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda i, pa, pb: (pa[i] // tile, 0)),
-            pl.BlockSpec((1, tile), lambda i, pa, pb: (pa[i] // tile + 1, 0)),
-            pl.BlockSpec((1, tile), lambda i, pa, pb: (pb[i] // tile, 0)),
-            pl.BlockSpec((1, tile), lambda i, pa, pb: (pb[i] // tile + 1, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, pa, pb: (i, 0)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_kernel, tile=tile, w=w),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        interpret=interpret,
-    )(pos_a.astype(jnp.int32), pos_b.astype(jnp.int32),
-      s_rows, s_rows, s_rows, s_rows)
-    return out[:, 0]
+    def body(sc, offs_, uts, _, outs):
+        a, b = (aligned_words(ut, o, 0, nw, 8) for ut, o in zip(uts, offs_))
+        outs[0][...] = jnp.minimum(first_diff(a, b, 8)[0], w)
+
+    def call(s, pos_a, pos_b):
+        rows, n_rows = stage_bytes(s, nw)
+        (lcp,) = paged_call(body, rows, n_rows, spw=4, nw=nw,
+                            starts=[pos_a, pos_b], out_rows=[1], tile=tile,
+                            interpret=interpret)
+        return lcp[0]
+
+    return per_read(call, s_padded, pos_a, pos_b)

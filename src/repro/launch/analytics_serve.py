@@ -23,6 +23,7 @@ import numpy as np
 from repro.core.analytics import AnalyticsEngine
 from repro.core.api import EraConfig, EraIndexer
 from repro.launch.warmstart import load_or_build
+from repro.launch.compile_cache import use_compile_cache
 
 
 def make_query(s: np.ndarray, rng: np.random.Generator, *, batch: int,
@@ -100,6 +101,7 @@ def serve_analytics(dataset_name: str = "dna", *, n: int = 100_000,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="dna")
     ap.add_argument("--n", type=int, default=100_000)

@@ -5,8 +5,11 @@ Maps the paper's two parallel architectures (§5) onto this machine:
 * **shared-memory / shared-disk** → multi-device single host: the string is
   replicated (one HBM copy per device), virtual trees are distributed by
   the fault-tolerant work queue, each device runs the elastic-range
-  pipeline on its groups.  Workers are simulated device contexts on CPU;
-  on a real pod each worker is one chip driven by the same loop.
+  pipeline on its groups.  Here the workers are simulated: they take
+  turns in one process, and every worker's groups run on the default
+  device (device 0), on CPU and on a TPU host alike — not one chip per
+  worker.  The multi-chip path is the sharded fabric
+  (``EraIndexer.build_sharded``).
 
 * **shared-nothing** → multi-pod: identical structure; the initial string
   broadcast cost (paper Table 3 excludes it; we report it) is modeled by
@@ -40,6 +43,7 @@ from repro.core.vertical import VerticalStats
 from repro.core.prepare import PrepareStats
 from repro.data.strings import dataset
 from repro.runtime.scheduler import WorkQueue
+from repro.launch.compile_cache import use_compile_cache
 
 
 # ---------------------------------------------------------------------------
@@ -161,6 +165,7 @@ def build_distributed(
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="dna")
     ap.add_argument("--n", type=int, default=200_000)
@@ -192,7 +197,7 @@ def main():
                          "roofline model), model = roofline model only")
     ap.add_argument("--autotune-table", default=None,
                     help="autotune table path (REPRO_AUTOTUNE_TABLE; "
-                         "default .repro_autotune.json)")
+                         "no table is read unless one is named)")
     args = ap.parse_args()
 
     import os

@@ -22,6 +22,7 @@ import numpy as np
 from repro.core.api import EraConfig, EraIndexer
 from repro.core.query import DeviceIndex
 from repro.launch.warmstart import load_or_build, will_load
+from repro.launch.compile_cache import use_compile_cache
 
 
 def make_workload(s: np.ndarray, rng: np.random.Generator, *, batch: int,
@@ -124,6 +125,7 @@ def serve_queries(dataset_name: str = "dna", *, n: int = 100_000,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="dna")
     ap.add_argument("--n", type=int, default=100_000)

@@ -56,9 +56,11 @@ def run(args) -> dict:
     from repro.core.api import EraConfig, EraIndexer
     from repro.core.prepare import subtree_prepare_batch
     from repro.data.strings import dataset
+    from repro.launch.compile_cache import use_compile_cache
 
     import jax
 
+    use_compile_cache()
     s, alphabet = dataset(args.dataset, args.n, seed=args.seed)
     cfg = EraConfig(memory_bytes=args.memory_bytes, r_bytes=4096,
                     build_impl="none")

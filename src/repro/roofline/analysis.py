@@ -27,9 +27,24 @@ from __future__ import annotations
 import dataclasses
 import re
 
-# TPU v5e-class hardware constants (assignment-specified)
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # B/s / chip
+# Published per-chip peaks keyed by ``jax.Device.device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s).  A device kind missing here has no prediction: callers get
+# None, never another chip's numbers.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9,
+                    "hbm_bytes": 16e9},
+}
+
+
+def device_peaks(device_kind: str) -> dict | None:
+    """The published peaks of one chip kind, or None if not tabled."""
+    return DEVICE_PEAKS.get(device_kind)
+
+
+# The dry-run analysis models a v5e slice (assignment-specified).
+PEAK_FLOPS = DEVICE_PEAKS["TPU v5 lite"]["flops_bf16"]   # bf16 / chip
+HBM_BW = DEVICE_PEAKS["TPU v5 lite"]["hbm_bw"]           # B/s / chip
 ICI_BW = 50e9            # B/s / link
 ICI_LINKS = 4            # usable links per chip on a 2D torus (x± / y±)
 

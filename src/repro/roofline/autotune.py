@@ -1,23 +1,22 @@
 """Roofline-driven tile autotuning for the gather-style Pallas kernels.
 
-Every kernel in :mod:`repro.kernels` walks the string through ``(1,
-tile)`` BlockSpec windows (see :func:`repro.kernels.tiles.stage_tiles`)
-and takes ``tile`` as a static argument that never changes results —
-only how much HBM each grid step DMAs and how much VMEM the two-tile
-halo window occupies.  Historically every call used a hard-coded
-``tile=2048``.  This module picks the tile per
-``(backend, kernel, dtype-bits, n-bucket)`` instead:
+Every kernel in :mod:`repro.kernels` takes ``tile`` as a static argument
+that never changes results — for the paged-read kernels it is the number
+of reads per grid step (:func:`repro.kernels.tiles.read_block` rounds it
+to a legal TPU block), for ``kmer_histogram`` the symbols per step.
+Historically every call used a hard-coded ``tile=2048``.  This module
+picks the tile per ``(backend, kernel, dtype-bits, n-bucket)`` instead:
 
-* **Model pick** — the VMEM/HBM roofline model of
-  :mod:`repro.roofline.analysis`: each grid step moves ``2 * tile *
-  4`` bytes HBM→VMEM (two int32 halo rows) plus its output row, so the
-  per-step time model is ``max(t_dispatch, dma_bytes / HBM_BW)``.  The
-  DMA term only reaches the fixed dispatch overhead at tiles far larger
-  than the VMEM budget allows, so the model selects the SMALLEST
-  feasible candidate: ``tile >= w_cap`` (kernels assert ``w <= tile``),
-  ``tile`` large enough that the per-step DMA amortizes the issue
-  overhead (``tile * 4 >= DMA_MIN_BYTES``), and the two-tile window
-  under the per-step VMEM budget.  Same histogram-bucket idiom as
+* **Model pick** — a VMEM/HBM roofline model with the constants of
+  :mod:`repro.roofline.analysis`: the per-step time model is
+  ``max(t_dispatch, dma_bytes / HBM_BW)`` with ``2 * tile * 4`` bytes
+  moved per step.  The DMA term only reaches the fixed dispatch overhead
+  at tiles far larger than the VMEM budget allows, so the model selects
+  the SMALLEST feasible candidate: ``tile >= w_cap``, ``tile`` large
+  enough that the per-step DMA amortizes the issue overhead (``tile * 4
+  >= DMA_MIN_BYTES``), and ``2 * tile * 4`` under the per-step VMEM
+  budget.  The model dates from the older one-row window kernels and
+  has not been refit to the paged read.  Same histogram-bucket idiom as
   :func:`repro.core.build.bucket_pad_widths` — ``n`` buckets to powers
   of two so one table entry covers a whole workload size class.
 * **Measured fallback** — :func:`measured_sweep` times a caller-supplied
@@ -29,8 +28,8 @@ Chosen tiles persist to a small JSON table (:class:`AutotuneTable`) that
 :mod:`repro.kernels.ops` consults at dispatch via :func:`tile_for`.
 Resolution order per key: explicit on-disk table entry → roofline model
 (when ``REPRO_AUTOTUNE=model`` or a table is active) → the kernel's
-static default.  The table path comes from ``REPRO_AUTOTUNE_TABLE``
-(default ``.repro_autotune.json`` in the working directory); dispatch
+static default.  A table is read only from the file
+``REPRO_AUTOTUNE_TABLE`` names (never from a default path); dispatch
 only ever READS the table — writing happens solely through
 :meth:`AutotuneTable.save` (driver flags / sweeps), so imports never
 touch disk.
@@ -193,8 +192,10 @@ _ACTIVE: AutotuneTable | None = None
 _LOADED_FROM: str | None = None
 
 
-def default_table_path() -> str:
-    return os.environ.get("REPRO_AUTOTUNE_TABLE", ".repro_autotune.json")
+def default_table_path() -> str | None:
+    """The table named by ``REPRO_AUTOTUNE_TABLE``, or None: dispatch
+    never reads a file the environment did not name."""
+    return os.environ.get("REPRO_AUTOTUNE_TABLE") or None
 
 
 def set_active_table(table: AutotuneTable | None) -> None:
@@ -207,7 +208,7 @@ def set_active_table(table: AutotuneTable | None) -> None:
 
 
 def active_table() -> AutotuneTable | None:
-    """The installed table, lazily loading the on-disk default once.  A
+    """The installed table, lazily loading the named file once.  A
     missing file is remembered as 'no table' — dispatch stays one dict
     probe, no per-call stat."""
     global _ACTIVE, _LOADED_FROM
@@ -215,7 +216,7 @@ def active_table() -> AutotuneTable | None:
         if _ACTIVE is not None:
             return _ACTIVE
         path = default_table_path()
-        if _LOADED_FROM == path:  # already probed and missing
+        if path is None or _LOADED_FROM == path:  # unnamed, or missing
             return None
         _LOADED_FROM = path
         if os.path.exists(path):

@@ -82,6 +82,15 @@ class TestShardedPrepare:
         got = fabric.sharded_prepare(sp, groups, cap, ecfg)
         _assert_states_equal(ref, got)
 
+    def test_compaction_off_bit_identical(self):
+        """``compact=False`` (EraConfig.compaction through build_sharded)
+        runs every iteration full width — same final state."""
+        _, _, ix, groups, cap, sp = _workload("dna", 6_000, 4096)
+        ecfg = ix.config.elastic_config()
+        ref = subtree_prepare_batch(sp, groups, cap, ecfg)
+        got = fabric.sharded_prepare(sp, groups, cap, ecfg, compact=False)
+        _assert_states_equal(ref, got)
+
     def test_one_shard_degenerate_mesh(self):
         _, _, ix, groups, cap, sp = _workload("dna", 6_000, 4096)
         ecfg = ix.config.elastic_config()

@@ -399,3 +399,43 @@ class TestFacade:
             assert list(tmp_path.iterdir()) == []
         finally:
             obs.configure(trace=was_t, metrics_on=was_m)
+
+
+class TestDispatchMarker:
+    """Kernel dispatch markers carry a roofline prediction only for a
+    device kind with published peaks, and name the implementation that
+    ran (compiled Pallas, interpreted Pallas or the jnp reference)."""
+
+    def test_peaks_are_keyed_by_device_kind(self):
+        from repro.roofline.analysis import device_peaks
+
+        assert device_peaks("TPU v5 lite")["hbm_bw"] == 819e9
+        assert device_peaks("cpu") is None
+
+    @pytest.mark.parametrize("kernels,impl", [("pallas", "interpret"),
+                                              ("jnp", "ref")])
+    def test_marker_on_an_untabled_device(self, kernels, impl, monkeypatch):
+        import jax.numpy as jnp
+
+        from repro import obs
+        from repro.core import packing
+        from repro.core.alphabet import DNA
+        from repro.kernels import ops
+
+        monkeypatch.setenv("REPRO_KERNELS", kernels)
+        pt = packing.pack_text(DNA.random_string(300, seed=1), DNA, extra=40)
+        was_t, was_m = obs.trace_enabled(), obs.metrics_enabled()
+        try:
+            obs.configure(trace=True, metrics_on=True, clear=True)
+            ops.range_gather_words(pt, jnp.arange(8, dtype=jnp.int32), 16)
+            marks = [e["args"] for e in obs.tracer().events()
+                     if e["name"] == "kernel/range_gather/dispatch"]
+            counts = {c["labels"]["impl"]: c["value"]
+                      for c in obs.metrics().snapshot()["counters"]
+                      if c["name"] == "kernel_dispatch_total"}
+        finally:
+            obs.configure(trace=was_t, metrics_on=was_m, clear=True)
+        assert [m["impl"] for m in marks] == [impl]
+        assert marks[0]["roofline_pred_bytes"] > 0
+        assert "roofline_hbm_us" not in marks[0]
+        assert counts == {impl: 1}

@@ -147,3 +147,34 @@ class TestDataPipeline:
         cfg = TokenPipelineConfig(vocab=50, batch=2, seq_len=8, seed=0)
         b = batch_at_step(cfg, 0)
         assert b["tokens"].shape == b["labels"].shape == (2, 8)
+
+
+class TestCompileCache:
+    """One placeable persistent-compilation-cache directory."""
+
+    @pytest.fixture
+    def restore_config(self):
+        before = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_environment_names_the_cache(self, monkeypatch, tmp_path,
+                                         restore_config):
+        from repro.launch.compile_cache import use_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+    def test_default_is_fixed_and_ignored_in_checkout(self, monkeypatch,
+                                                      restore_config):
+        from pathlib import Path
+
+        from repro.launch.compile_cache import use_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = Path(__file__).resolve().parents[1]
+        path = use_compile_cache()
+        assert path == str(root / ".jax_cache") == use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()

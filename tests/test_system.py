@@ -84,3 +84,21 @@ class TestDedupPipeline:
         seqs[3] = seqs[1]  # exact duplicate content
         keep = dedup_mask(seqs, min_repeat=32)
         assert keep.sum() < 6  # at least one of the duplicates flagged
+
+
+class TestBenchmarkRunner:
+    @pytest.mark.parametrize("mode", [[], ["--smoke"]])
+    def test_a_failed_suite_fails_the_run(self, mode, monkeypatch):
+        """Every mode exits non-zero when a suite raised."""
+        import sys
+
+        from benchmarks import bench_fabric, run
+
+        def boom(quick=True):
+            raise RuntimeError("suite failed")
+
+        monkeypatch.setattr(bench_fabric, "run", boom)
+        monkeypatch.setattr(sys, "argv", ["run", "--only", "fabric", *mode])
+        with pytest.raises(SystemExit) as e:
+            run.main()
+        assert e.value.code == 1
