@@ -5,11 +5,12 @@ a warm jit cache — once with tracing/metrics ON, once OFF — then:
 
 1. exports the Chrome trace and validates it against the ``trace_event``
    schema subset (:func:`repro.obs.validate_chrome_trace`);
-2. asserts the per-batch serving spans the ISSUE names are present
-   (``serve/queue_wait``, ``serve/pad_pack``, ``serve/device_dispatch``,
-   ``serve/consume_sync``) plus the construction spans;
+2. asserts the per-batch serving spans are present (``serve/queue_wait``,
+   ``serve/route``, ``serve/pad_pack``, ``serve/device_dispatch``,
+   ``serve/consume_sync``, ``serve/scatter``) plus the construction spans
+   of a streaming and a one-shot build;
 3. asserts the Prometheus snapshot carries the cache hit rate, the
-   batch-fill histogram, and per-impl kernel dispatch counters;
+   batch-fill histogram, and per-impl kernel trace counters;
 4. gates overhead: instrumented qps must stay within ``--threshold`` of
    the uninstrumented run (default 0.5 — CI runners are noisy; the guard
    is against pathological slowdowns, not 5% drift).
@@ -32,20 +33,24 @@ import numpy as np
 
 REQUIRED_SPANS = (
     "build/vertical",
+    "build/flatten",
     "prepare/step",
+    "prepare/readback",
     "stream/pipeline",
     "stream/chunk",
     "serve/queue_wait",
+    "serve/route",
     "serve/pad_pack",
     "serve/device_dispatch",
     "serve/consume_sync",
+    "serve/scatter",
 )
 REQUIRED_PROM = (
     "serve_cache_hit_rate",
     "serve_batch_fill_bucket",
     "serve_queue_wait_ms_bucket",
     "serve_batch_age_ms_bucket",
-    "kernel_dispatch_total",
+    "kernel_traces_total",
     "prepare_group_iterations_bucket",
 )
 
@@ -92,6 +97,10 @@ def main() -> None:
         s, device_budget=64 << 10, max_pattern_len=64)
     print(f"stream build: {sreport.n_chunks} chunks, "
           f"overlap_frac={sreport.overlap_frac:.2f}")
+    # the one-shot build adds build/flatten; both give the same leaves
+    one_shot = indexer.build_device(s, max_pattern_len=64)
+    if not np.array_equal(one_shot.ell_host, dev.ell_host):
+        problems.append("stream and one-shot builds differ")
     rng = np.random.default_rng(7)
     pats = make_hot_workload(s, rng, n_requests=args.requests, hot_pool=32,
                              hot_frac=0.8, min_len=4, max_len=24,
@@ -140,7 +149,7 @@ def main() -> None:
         if needle not in prom:
             problems.append(f"prometheus snapshot missing {needle!r}")
     if 'impl="pallas"' not in prom and 'impl="ref"' not in prom:
-        problems.append("kernel dispatch counters carry no impl label")
+        problems.append("kernel trace counters carry no impl label")
 
     # ---- uninstrumented arm: same warm jit cache, recorder off ------------
     obs.configure(trace=False, metrics_on=False)

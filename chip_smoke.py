@@ -165,14 +165,14 @@ def check_dispatch() -> None:
     counts: dict[str, float] = {}
     impls: set[str] = set()
     for c in obs.metrics().snapshot()["counters"]:
-        if c["name"] == "kernel_dispatch_total":
+        if c["name"] == "kernel_traces_total":
             labels = c["labels"]
             key = f"{labels['kernel']}/{labels['currency']}/{labels['impl']}"
             counts[key] = counts.get(key, 0) + c["value"]
             impls.add(labels["impl"])
-    log(f"kernel dispatches (traces): {json.dumps(counts, sort_keys=True)}")
+    log(f"kernel traces: {json.dumps(counts, sort_keys=True)}")
     check(impls == {"pallas"},
-          "every kernel dispatch ran compiled Pallas (0 ref, 0 interpreted)")
+          "every kernel traced is compiled Pallas (0 ref, 0 interpreted)")
 
 
 def one_chip(s, alphabet, rng) -> None:

@@ -385,7 +385,8 @@ class EraIndexer:
         if not groups:
             return groups, None, None
         capacity = self._capacity(groups)
-        s_padded = self._device_text(s)
+        with obs.tracer().span("build/device_text", n=len(s)):
+            s_padded = self._device_text(s)
         t0 = time.perf_counter()
         states = subtree_prepare_batch(s_padded, groups, capacity,
                                        self.config.elastic_config(),
@@ -502,18 +503,20 @@ class EraIndexer:
 
         from repro.core.query import DeviceIndex  # local: avoid import cycle
 
-        groups, states, _ = self._prepare_batched(s, report)
-        if states is None:
-            raise ValueError("cannot flatten an empty index")
-        prefixes, freqs, ell = _flatten_state(groups, states)
-        return DeviceIndex.from_prepare(
-            alphabet=self.alphabet,
-            s=np.asarray(s),
-            prefixes=prefixes,
-            freqs=freqs,
-            ell=ell,
-            **device_kwargs,
-        )
+        with obs.tracer().span("build/total", n=len(s), engine="batched"):
+            groups, states, _ = self._prepare_batched(s, report)
+            if states is None:
+                raise ValueError("cannot flatten an empty index")
+            with obs.tracer().span("build/flatten", groups=len(groups)):
+                prefixes, freqs, ell = _flatten_state(groups, states)
+                return DeviceIndex.from_prepare(
+                    alphabet=self.alphabet,
+                    s=np.asarray(s),
+                    prefixes=prefixes,
+                    freqs=freqs,
+                    ell=ell,
+                    **device_kwargs,
+                )
 
     def build_stream(self, s: np.ndarray, report: BuildReport | None = None,
                      *, device_budget: int | None = None,

@@ -565,12 +565,13 @@ def subtree_prepare(
             with obs.tracer().span("prepare/step", w=w, n_active=n_active):
                 state, n_active_dev = _jit_step(s_padded, state, w,
                                                 use_pallas, word_keys)
-            if stats is not None:
-                stats.iterations += 1
-                stats.ranges.append(w)
-                stats.active_history.append(n_active)
-                stats.symbols_fetched += n_active * w
-            n_active = int(n_active_dev)
+                if stats is not None:
+                    stats.iterations += 1
+                    stats.ranges.append(w)
+                    stats.active_history.append(n_active)
+                    stats.symbols_fetched += n_active * w
+                with obs.tracer().span("prepare/readback"):
+                    n_active = int(n_active_dev)
             it += 1
         sp.set(iterations=it)
     _record_prepare_metrics([it], time.perf_counter() - t0, cfg)
@@ -636,6 +637,8 @@ def subtree_prepare_batch(
             group_iters += n_active > 0
             f_prime = (compaction_width(int(n_active.max()), capacity)
                        if compact else None)
+            # one span per iteration, from the enqueue to the active
+            # counts on the host: the read-back blocks until the step ends
             with obs.tracer().span("prepare/step", w=w,
                                    n_active=int(n_active.sum()),
                                    groups_active=int((n_active > 0).sum()),
@@ -648,13 +651,14 @@ def subtree_prepare_batch(
                     states, n_active_dev = _jit_step_batch(
                         s_padded, states, w, use_pallas, word_keys,
                         sort_fuse)
-            if stats is not None:
-                total_active = int(n_active.sum())
-                stats.iterations += 1
-                stats.ranges.append(w)
-                stats.active_history.append(total_active)
-                stats.symbols_fetched += total_active * w
-            n_active = np.asarray(n_active_dev)
+                if stats is not None:
+                    total_active = int(n_active.sum())
+                    stats.iterations += 1
+                    stats.ranges.append(w)
+                    stats.active_history.append(total_active)
+                    stats.symbols_fetched += total_active * w
+                with obs.tracer().span("prepare/readback"):
+                    n_active = np.asarray(n_active_dev)
             it += 1
         sp.set(iterations=it)
     _record_prepare_metrics(group_iters.tolist(),
@@ -810,19 +814,21 @@ def subtree_prepare_stream(
                             states, n_active_dev = _jit_step_batch(
                                 s_padded, states, w, use_pallas, word_keys,
                                 sort_fuse)
-                    if overlap and standby is None and host_next is not None:
-                        # the step above is dispatched asynchronously —
-                        # issue the standby copy now so it transfers
-                        # behind the chunk's in-flight elastic loop
-                        t_issue = time.perf_counter()
-                        standby = jax.device_put(host_next)
-                    if stats is not None:
-                        total_active = int(n_active.sum())
-                        stats.iterations += 1
-                        stats.ranges.append(w)
-                        stats.active_history.append(total_active)
-                        stats.symbols_fetched += total_active * w
-                    n_active = np.asarray(n_active_dev)
+                        if (overlap and standby is None
+                                and host_next is not None):
+                            # the step above is dispatched asynchronously
+                            # — issue the standby copy now so it transfers
+                            # behind the chunk's in-flight elastic loop
+                            t_issue = time.perf_counter()
+                            standby = jax.device_put(host_next)
+                        if stats is not None:
+                            total_active = int(n_active.sum())
+                            stats.iterations += 1
+                            stats.ranges.append(w)
+                            stats.active_history.append(total_active)
+                            stats.symbols_fetched += total_active * w
+                        with obs.tracer().span("prepare/readback"):
+                            n_active = np.asarray(n_active_dev)
                     it += 1
                 sp.set(iterations=it)
             rep.iterations += it

@@ -50,16 +50,16 @@ from repro.kernels.probe_gather import (
 from repro.kernels.range_gather import range_gather_pack as _gather_pallas
 from repro.kernels.suffix_lcp import suffix_lcp_pairs as _suffix_lcp_pallas
 from repro.kernels import tiles as _tiles
-from repro.roofline.analysis import device_peaks
 
 
 # ---------------------------------------------------------------------------
-# Kernel-dispatch telemetry (REPRO_METRICS).  The record helper runs in the
-# impl closures' Python bodies: under jit that is TRACE time, so the counters
-# count (re)compilations per distinct padded shape — exactly the jit-cache
-# pressure signal the serving/bench layers need — while eager callers count
-# every call.  ``kernel_distinct_shapes_total`` is the recompile proxy: it
-# grows only when a (kernel, currency, shape) triple is first seen.
+# Kernel-trace telemetry (REPRO_METRICS / REPRO_TRACE).  The record helper
+# runs in the impl closures' Python bodies: under jit that is TRACE time, so
+# ``kernel_traces_total`` counts jit traces per distinct padded shape — the
+# jit-cache pressure signal the serving/bench layers need — not executions
+# (eager callers trace on every call).  ``kernel_distinct_shapes_total`` is
+# the recompile proxy: it grows only when a (kernel, currency, shape) triple
+# is first seen.  Kernel device time comes from a profiler trace, not here.
 # ---------------------------------------------------------------------------
 
 _SHAPES_SEEN: set[tuple] = set()
@@ -67,36 +67,22 @@ _SHAPES_LOCK = threading.Lock()
 
 
 def _record(kernel: str, use_pallas: bool, currency: str, *arrays,
-            tile: int = 0, w: int = 0, bits: int | None = None) -> None:
+            tile: int = 0) -> None:
     if not use_pallas:
         impl = "ref"
     else:
         impl = "interpret" if _tiles.default_interpret(None) else "pallas"
     if obs.trace_enabled():
         rows = int(arrays[0].shape[0]) if arrays else 0
-        fields = dict(kernel=kernel, impl=impl, currency=currency, rows=rows,
-                      tile=tile)
-        if bits is not None:
-            # Roofline prediction for a paged read of ``w`` symbols of
-            # ``bits`` bits: every row DMAs the 128-word HBM rows holding
-            # its window, and the compare work is ~w symbol lanes per
-            # row.  Perfetto viewers divide the enclosing span's wall time
-            # by these to read achieved-vs-predicted throughput.  Only a
-            # device kind with published peaks gets an HBM time.
-            nw = -(-max(w, 1) * bits // 32)
-            fields.update(roofline_pred_bytes=rows * _tiles.window_bytes(nw),
-                          roofline_pred_flops=rows * max(w, 1))
-            peaks = device_peaks(jax.devices()[0].device_kind)
-            if peaks is not None:
-                fields["roofline_hbm_us"] = (
-                    fields["roofline_pred_bytes"] / peaks["hbm_bw"] * 1e6)
-        obs.tracer().instant(f"kernel/{kernel}/dispatch", **fields)
+        obs.tracer().instant(f"kernel/{kernel}/trace", kernel=kernel,
+                             impl=impl, currency=currency, rows=rows,
+                             tile=tile)
     if not obs.metrics_enabled():
         return
     m = obs.metrics()
-    m.counter("kernel_dispatch_total",
-              "kernel impl dispatches (trace-time under jit: counts "
-              "compilations per padded shape)",
+    m.counter("kernel_traces_total",
+              "kernel impl jit traces (one per traced padded shape under "
+              "jit, one per call when eager; not executions)",
               kernel=kernel, impl=impl, currency=currency).inc()
     shape = tuple(tuple(getattr(a, "shape", ())) for a in arrays)
     key = (kernel, currency, shape)
@@ -184,13 +170,11 @@ def range_gather_impl(use_pallas: bool):
     def fn(s_text, offs, w: int):
         tile = _tile("range_gather", s_text, w)
         if isinstance(s_text, PackedText):
-            _record("range_gather", use_pallas, "packed", offs,
-                    tile=tile, w=w, bits=s_text.bits)
+            _record("range_gather", use_pallas, "packed", offs, tile=tile)
             if use_pallas:
                 return _packed_gather_pallas(s_text, offs, w, tile=tile)
             return _ref.range_gather_packed_ref(s_text, offs, w)
-        _record("range_gather", use_pallas, "byte", offs, tile=tile, w=w,
-                bits=8)
+        _record("range_gather", use_pallas, "byte", offs, tile=tile)
         if use_pallas:
             return _gather_pallas(s_text, offs, w, tile=tile)
         return _ref.range_gather_pack_ref(s_text, offs, w)
@@ -204,7 +188,7 @@ def range_gather_pack(s_text, offs, w: int):
 def kmer_histogram(s_padded, n: int, k: int, base: int):
     use_pallas = _use_pallas()
     tile = _tile("kmer_histogram", s_padded, k)
-    _record("kmer_histogram", use_pallas, "byte", s_padded, tile=tile, w=k)
+    _record("kmer_histogram", use_pallas, "byte", s_padded, tile=tile)
     if use_pallas:
         return _kmer_pallas(s_padded, n, k, base, tile=tile)
     return _ref.kmer_histogram_ref(s_padded, n, k, base)
@@ -216,8 +200,7 @@ def range_gather_words_impl(use_pallas: bool):
     only — the word currency has no byte-string form)."""
     def fn(pt: PackedText, offs, w: int):
         tile = _tile("range_gather_words", pt, w)
-        _record("range_gather", use_pallas, "word", offs, tile=tile, w=w,
-                bits=pt.bits)
+        _record("range_gather", use_pallas, "word", offs, tile=tile)
         if use_pallas:
             return _words_gather_pallas(pt, offs, w, tile=tile)
         return _ref.range_gather_words_ref(pt, offs, w)
@@ -233,8 +216,7 @@ def suffix_lcp_pairs(s_text, pos_a, pos_b, w: int):
     if isinstance(s_text, PackedText):
         if _use_word_compare():
             # word path: first differing dense word + clz, no byte repack
-            _record("suffix_lcp", _use_pallas(), "word", pos_a,
-                    tile=tile, w=w, bits=s_text.bits)
+            _record("suffix_lcp", _use_pallas(), "word", pos_a, tile=tile)
             if _use_pallas():
                 return _words_lcp_pallas(s_text, pos_a, pos_b, w, tile=tile)
             return _ref.suffix_lcp_words_ref(s_text, pos_a, pos_b, w)
@@ -244,15 +226,14 @@ def suffix_lcp_pairs(s_text, pos_a, pos_b, w: int):
         a = gather(s_text, pos_a, w)
         b = gather(s_text, pos_b, w)
         return lcp_pairs(a, b, w)[0]
-    _record("suffix_lcp", _use_pallas(), "byte", pos_a, tile=tile, w=w,
-            bits=8)
+    _record("suffix_lcp", _use_pallas(), "byte", pos_a, tile=tile)
     if _use_pallas():
         return _suffix_lcp_pallas(s_text, pos_a, pos_b, w, tile=tile)
     return _ref.suffix_lcp_pairs_ref(s_text, pos_a, pos_b, w)
 
 
 def lcp_pairs(a, b, w: int):
-    _record("lcp_pairs", _use_pallas(), "byte", a, w=w)
+    _record("lcp_pairs", _use_pallas(), "byte", a)
     if _use_pallas():
         return _lcp_pallas(a, b, w)
     return _ref.lcp_pairs_ref(a, b, w)
@@ -268,14 +249,14 @@ def pattern_probe_impl(use_pallas: bool):
         tile = _tile("pattern_probe", s_text, w)
         if isinstance(s_text, PackedText):
             _record("pattern_probe", use_pallas, "packed", pos, pat_words,
-                    tile=tile, w=w, bits=s_text.bits)
+                    tile=tile)
             if use_pallas:
                 return _packed_probe_pallas(s_text, pos, pat_words,
                                             mask_words, tile=tile)
             return _ref.pattern_probe_packed_ref(s_text, pos, pat_words,
                                                  mask_words)
         _record("pattern_probe", use_pallas, "byte", pos, pat_words,
-                tile=tile, w=w, bits=8)
+                tile=tile)
         if use_pallas:
             return _probe_pallas(s_text, pos, pat_words, mask_words,
                                  tile=tile)
@@ -297,7 +278,7 @@ def pattern_probe_words_impl(use_pallas: bool):
         w = pat_dense.shape[1] * (32 // pt.bits)
         tile = _tile("pattern_probe_words", pt, w)
         _record("pattern_probe", use_pallas, "word", pos, pat_dense,
-                tile=tile, w=w, bits=pt.bits)
+                tile=tile)
         if use_pallas:
             return _words_probe_pallas(pt, pos, pat_dense, mask_dense,
                                        lengths, lim_p, tile=tile)
@@ -322,7 +303,7 @@ def probe_gather_words_impl(use_pallas: bool):
         w = max(pat_dense.shape[1] * (32 // pt.bits), fetch)
         tile = _tile("probe_gather_words", pt, w)
         _record("probe_gather", use_pallas, "word", pos, pat_dense,
-                tile=tile, w=w, bits=pt.bits)
+                tile=tile)
         if use_pallas:
             return _fused_words_pallas(pt, pos, pat_dense, mask_dense,
                                        lengths, lim_p, fetch=fetch,
@@ -353,7 +334,7 @@ def probe_gather_impl(use_pallas: bool):
             w = max(pat_words.shape[1] * 4, fetch)
             tile = _tile("probe_gather", s_text, w)
             _record("probe_gather", use_pallas, "packed", pos, pat_words,
-                    tile=tile, w=w, bits=s_text.bits)
+                    tile=tile)
             if use_pallas:
                 return _fused_packed_pallas(s_text, pos, pat_words,
                                             mask_words, fetch=fetch,

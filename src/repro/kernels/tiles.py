@@ -123,11 +123,6 @@ def n_windows(nw: int) -> int:
     return -(-(nw + 1) // LANES)
 
 
-def window_bytes(nw: int) -> int:
-    """HBM bytes one read of ``nw`` words DMAs: its 128-word rows."""
-    return (n_windows(nw) + 1) * LANES * 4
-
-
 def read_windows(word0_ref, s_hbm, win, sem, u_scr, ut_ref, *,
                  n_rows: int, nwin: int) -> None:
     """Fill ``ut_ref[j, r]`` with word ``word0[r] + j`` of the staged rows,

@@ -300,8 +300,6 @@ class AsyncServer:
         if self.sharded:
             return self._dispatch_sharded(requests)
         cfg = self.config
-        keys = [self.dev.route_key(r.pattern) for r in requests]
-
         # with the cache OFF this is the honest one-row-per-request
         # baseline (what query_serve does); the cache brings both the
         # cross-batch memo AND in-batch dedup of repeated hot patterns
@@ -310,24 +308,26 @@ class AsyncServer:
         key_row: dict[tuple, int] = {}
         miss_req: list[_Request] = []
         hit_vals: dict[tuple, tuple] = {}
-        for req, key in zip(requests, keys):
-            if caching:
-                if key in hit_vals:
-                    row_of.append(None)
-                    continue
-                if key in key_row:
-                    row_of.append(key_row[key])
-                    continue
-                val = self.cache.get(key)
-                if val is not None:
-                    self._m_cache_hits.inc()
-                    hit_vals[key] = val
-                    row_of.append(None)
-                    continue
-                self._m_cache_misses.inc()
-                key_row[key] = len(miss_req)
-            row_of.append(len(miss_req))
-            miss_req.append(req)
+        with self._tr.span("serve/route", rows=len(requests)):
+            keys = [self.dev.route_key(r.pattern) for r in requests]
+            for req, key in zip(requests, keys):
+                if caching:
+                    if key in hit_vals:
+                        row_of.append(None)
+                        continue
+                    if key in key_row:
+                        row_of.append(key_row[key])
+                        continue
+                    val = self.cache.get(key)
+                    if val is not None:
+                        self._m_cache_hits.inc()
+                        hit_vals[key] = val
+                        row_of.append(None)
+                        continue
+                    self._m_cache_misses.inc()
+                    key_row[key] = len(miss_req)
+                row_of.append(len(miss_req))
+                miss_req.append(req)
 
         handles = (hit_vals,)
         n_rows = len(miss_req)
@@ -377,38 +377,39 @@ class AsyncServer:
         (lowest covered) shard's RouteCache — route→shard is
         deterministic, so the per-shard caches partition the key space."""
         cfg = self.config
-        keys = [self.dev.route_key(r.pattern) for r in requests]
         caching = cfg.cache_size > 0
         # per request: None = cache hit, else [(shard, local row), ...]
         row_of: list[list | None] = []
         key_rows: dict[tuple, list] = {}
         hit_vals: dict[tuple, tuple] = {}
         shard_req: dict[int, list[_Request]] = {}
-        for req, key in zip(requests, keys):
-            if caching:
-                if key in hit_vals:
-                    row_of.append(None)
-                    continue
-                if key in key_rows:  # in-batch duplicate: share the rows
-                    row_of.append(key_rows[key])
-                    continue
-            lo, hi = self.dev.shard_span(req.pattern)
-            if caching:
-                val = self.caches[lo].get(key)
-                if val is not None:
-                    self._m_cache_hits.inc()
-                    hit_vals[key] = val
-                    row_of.append(None)
-                    continue
-                self._m_cache_misses.inc()
-            rows = []
-            for k in range(lo, hi + 1):
-                local = shard_req.setdefault(k, [])
-                rows.append((k, len(local)))
-                local.append(req)
-            if caching:
-                key_rows[key] = rows
-            row_of.append(rows)
+        with self._tr.span("serve/route", rows=len(requests)):
+            keys = [self.dev.route_key(r.pattern) for r in requests]
+            for req, key in zip(requests, keys):
+                if caching:
+                    if key in hit_vals:
+                        row_of.append(None)
+                        continue
+                    if key in key_rows:  # in-batch duplicate: share rows
+                        row_of.append(key_rows[key])
+                        continue
+                lo, hi = self.dev.shard_span(req.pattern)
+                if caching:
+                    val = self.caches[lo].get(key)
+                    if val is not None:
+                        self._m_cache_hits.inc()
+                        hit_vals[key] = val
+                        row_of.append(None)
+                        continue
+                    self._m_cache_misses.inc()
+                rows = []
+                for k in range(lo, hi + 1):
+                    local = shard_req.setdefault(k, [])
+                    rows.append((k, len(local)))
+                    local.append(req)
+                if caching:
+                    key_rows[key] = rows
+                row_of.append(rows)
 
         # shard k -> (real rows, start, count, win) device handles
         shard_handles: dict[int, tuple] = {}
@@ -467,23 +468,24 @@ class AsyncServer:
         done: dict[int, tuple] = {}
         caching = cfg.cache_size > 0
         now = time.perf_counter()
-        for req, key, row in zip(flight.requests, flight.keys,
-                                 flight.row_of):
-            if row is None:
-                val = hit_vals[key]
-            elif row in done:  # in-batch duplicate of a shared row
-                val = done[row]
-            else:
-                s, c = int(start[row]), int(count[row])
-                # cache the MATERIALIZED response: hot repeats skip the
-                # ell slice + sort, not just the device search
-                val = (np.sort(ell[s : s + c].astype(np.int64)),
-                       win[row].copy() if cfg.fetch else None)
-                done[row] = val
-                if caching:
-                    self.cache.put(key, val)
-            self.results[req.rid] = val
-            self.latency_s.append(now - req.t_admit)
+        with self._tr.span("serve/scatter", rows=len(flight.requests)):
+            for req, key, row in zip(flight.requests, flight.keys,
+                                     flight.row_of):
+                if row is None:
+                    val = hit_vals[key]
+                elif row in done:  # in-batch duplicate of a shared row
+                    val = done[row]
+                else:
+                    s, c = int(start[row]), int(count[row])
+                    # cache the MATERIALIZED response: hot repeats skip
+                    # the ell slice + sort, not just the device search
+                    val = (np.sort(ell[s : s + c].astype(np.int64)),
+                           win[row].copy() if cfg.fetch else None)
+                    done[row] = val
+                    if caching:
+                        self.cache.put(key, val)
+                self.results[req.rid] = val
+                self.latency_s.append(now - req.t_admit)
 
     def _consume_sharded(self, flight: _InFlight) -> None:
         """Materialize every shard's sub-batch and merge per request:
@@ -502,32 +504,33 @@ class AsyncServer:
         done: dict[tuple, tuple] = {}
         caching = cfg.cache_size > 0
         now = time.perf_counter()
-        for req, key, rows in zip(flight.requests, flight.keys,
-                                  flight.row_of):
-            if rows is None:
-                val = hit_vals[key]
-            elif tuple(rows) in done:
-                val = done[tuple(rows)]
-            else:
-                parts, win_out = [], None
-                for k, row in rows:
-                    start, count, win = mats[k]
-                    s, c = int(start[row]), int(count[row])
-                    if c:
-                        ell = self.dev.shards[k].ell_host
-                        parts.append(ell[s : s + c].astype(np.int64))
-                        if cfg.fetch and win_out is None:
-                            win_out = win[row].copy()
-                if cfg.fetch and win_out is None:
-                    win_out = np.full(cfg.fetch, -1, np.int32)
-                pos = (np.sort(np.concatenate(parts)) if parts
-                       else np.empty(0, np.int64))
-                val = (pos, win_out if cfg.fetch else None)
-                done[tuple(rows)] = val
-                if caching:
-                    self.caches[rows[0][0]].put(key, val)
-            self.results[req.rid] = val
-            self.latency_s.append(now - req.t_admit)
+        with self._tr.span("serve/scatter", rows=len(flight.requests)):
+            for req, key, rows in zip(flight.requests, flight.keys,
+                                      flight.row_of):
+                if rows is None:
+                    val = hit_vals[key]
+                elif tuple(rows) in done:
+                    val = done[tuple(rows)]
+                else:
+                    parts, win_out = [], None
+                    for k, row in rows:
+                        start, count, win = mats[k]
+                        s, c = int(start[row]), int(count[row])
+                        if c:
+                            ell = self.dev.shards[k].ell_host
+                            parts.append(ell[s : s + c].astype(np.int64))
+                            if cfg.fetch and win_out is None:
+                                win_out = win[row].copy()
+                    if cfg.fetch and win_out is None:
+                        win_out = np.full(cfg.fetch, -1, np.int32)
+                    pos = (np.sort(np.concatenate(parts)) if parts
+                           else np.empty(0, np.int64))
+                    val = (pos, win_out if cfg.fetch else None)
+                    done[tuple(rows)] = val
+                    if caching:
+                        self.caches[rows[0][0]].put(key, val)
+                self.results[req.rid] = val
+                self.latency_s.append(now - req.t_admit)
 
     # ---- live index swap --------------------------------------------------
 

@@ -13,9 +13,14 @@ gated on env knobs following the ``REPRO_SERVE_*`` idiom:
 
 Overhead budget (the contract instrumented hot paths rely on): with the
 knobs unset, ``tracer().span(...)`` is an attribute check returning the
-shared null span and ``metrics().counter(...)`` returns the shared null
-instrument — a dict-lookup-and-no-op ceiling, verified by
-``tests/test_obs.py`` and the CI trace-smoke overhead gate.
+shared null span (no import, no allocation) and ``metrics().counter(...)``
+returns the shared null instrument — a dict-lookup-and-no-op ceiling,
+verified by ``tests/test_obs.py`` and the CI trace-smoke overhead gate.
+With tracing on, each span also holds a ``jax.profiler.TraceAnnotation``
+of its name (imported on the first enabled span), which puts the span on
+the host plane of a ``jax.profiler`` trace, on the device trace's clock;
+with no profiler running the annotation is an inactive TraceMe, a few
+hundred nanoseconds a span.
 
 Enablement is resolved when an instrument is CREATED: call
 :func:`configure` (tests, smoke drivers) before building the objects you

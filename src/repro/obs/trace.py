@@ -2,12 +2,25 @@
 
 A :class:`Tracer` records *spans* — named wall-clock intervals with
 arbitrary key/value attributes — into a lock-protected in-memory ring
-buffer (a bounded ``deque``: the recorder never grows without bound, old
-spans fall off the back).  Spans nest per thread: the exporters carry a
+buffer (a bounded list: the recorder never grows without bound, old
+spans fall off the front).  Spans nest per thread: the exporters carry a
 ``depth`` per event and Chrome/Perfetto nests complete events on the same
-thread track automatically, so the serving loop's ``serve/pump`` >
-``serve/pad_pack`` > ``serve/device_dispatch`` hierarchy renders as a
-flame graph with zero extra bookkeeping.
+thread track automatically, so the serving loop's per-batch spans
+(``serve/route``, ``serve/pad_pack``, ``serve/device_dispatch``, then the
+previous batch's ``serve/consume_sync`` and ``serve/scatter``) and the
+construction's ``build/total`` > ``prepare/batch_loop`` > ``prepare/step``
+> ``prepare/readback`` render as a flame graph with zero extra
+bookkeeping.
+
+One clock with the device trace: while tracing is enabled every
+:meth:`Tracer.span` also holds a ``jax.profiler.TraceAnnotation`` of the
+same name for its life, so a ``jax.profiler`` trace of the process shows
+the program's spans on its host plane, on the profiler's clock, beside
+the device's ops — an idle gap of the device is named by the span the
+host was in.  With no profiler running the annotation is an inactive
+TraceMe (a few hundred nanoseconds).  :meth:`Tracer.complete` (a span
+timed after the fact, such as ``serve/queue_wait``) and
+:meth:`Tracer.instant` stay in this recorder only.
 
 Two exporters:
 
@@ -21,8 +34,10 @@ Two exporters:
 Overhead contract (the reason this module has no dependencies and no
 clever features): when tracing is disabled every ``span()`` call returns
 the shared :data:`NULL_SPAN` singleton after one attribute check — no
-allocation, no clock read, no lock.  The enabled-path cost is two
-``perf_counter_ns`` reads plus one locked ``deque.append`` per span.
+allocation, no clock read, no lock, no import.  The enabled-path cost is
+two ``perf_counter_ns`` reads, one locked list append and one profiler
+annotation per span; ``jax.profiler`` is imported on the first enabled
+span, so this module imports without JAX.
 
 Enable with ``REPRO_TRACE=1`` (the ``REPRO_SERVE_*`` env idiom) or
 programmatically via :func:`repro.obs.configure`.
@@ -54,11 +69,28 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+_annotation = None
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on the first enabled
+    span; a null context where JAX is not installed."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = lambda name: NULL_SPAN  # noqa: E731
+        _annotation = TraceAnnotation
+    return _annotation
+
+
 class _Span:
     """One live span (context manager); records itself into the tracer
-    ring buffer on exit.  ``set(**attrs)`` adds attributes mid-span."""
+    ring buffer on exit, and holds a profiler annotation of its name
+    meanwhile.  ``set(**attrs)`` adds attributes mid-span."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -66,15 +98,20 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
+        self._ann = _profiler_annotation()(self.name)
+        self._ann.__enter__()
         self._depth = self._tracer._push()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter_ns() - self._t0
-        self._tracer._pop()
-        self._tracer._record(self.name, self._t0, dur, self._depth,
-                             self.attrs)
+        try:
+            self._tracer._pop()
+            self._tracer._record(self.name, self._t0, dur, self._depth,
+                                 self.attrs)
+        finally:
+            self._ann.__exit__(*exc)
         return False
 
     def set(self, **attrs) -> None:
@@ -105,13 +142,15 @@ class Tracer:
     # ---- recording --------------------------------------------------------
 
     def span(self, name: str, **attrs):
-        """A context manager timing ``name``; disabled -> :data:`NULL_SPAN`."""
+        """A context manager timing ``name`` (and holding a profiler
+        annotation of it); disabled -> :data:`NULL_SPAN`."""
         if not self.enabled:
             return NULL_SPAN
         return _Span(self, name, attrs)
 
     def instant(self, name: str, **attrs) -> None:
-        """A zero-duration point event (rendered as an arrow/mark)."""
+        """A zero-duration point event (rendered as an arrow/mark); this
+        recorder only, no profiler annotation."""
         if not self.enabled:
             return
         self._record(name, time.perf_counter_ns(), 0,
@@ -120,7 +159,8 @@ class Tracer:
     def complete(self, name: str, t_start_ns: int, dur_ns: int,
                  **attrs) -> None:
         """Record an explicitly-timed span (e.g. a queue wait measured
-        from a request's admission timestamp)."""
+        from a request's admission timestamp).  It is timed after the
+        fact, so it reaches no profiler trace: this recorder only."""
         if not self.enabled:
             return
         self._record(name, t_start_ns, dur_ns,

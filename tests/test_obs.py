@@ -402,9 +402,10 @@ class TestFacade:
 
 
 class TestDispatchMarker:
-    """Kernel dispatch markers carry a roofline prediction only for a
-    device kind with published peaks, and name the implementation that
-    ran (compiled Pallas, interpreted Pallas or the jnp reference)."""
+    """Kernel trace markers fire once per jit trace, carry no roofline
+    prediction, and name the implementation traced (compiled Pallas,
+    interpreted Pallas or the jnp reference); the device peaks stay keyed
+    by device kind for the benchmark's roofline shares."""
 
     def test_peaks_are_keyed_by_device_kind(self):
         from repro.roofline.analysis import device_peaks
@@ -429,13 +430,221 @@ class TestDispatchMarker:
             obs.configure(trace=True, metrics_on=True, clear=True)
             ops.range_gather_words(pt, jnp.arange(8, dtype=jnp.int32), 16)
             marks = [e["args"] for e in obs.tracer().events()
-                     if e["name"] == "kernel/range_gather/dispatch"]
+                     if e["name"] == "kernel/range_gather/trace"]
             counts = {c["labels"]["impl"]: c["value"]
                       for c in obs.metrics().snapshot()["counters"]
-                      if c["name"] == "kernel_dispatch_total"}
+                      if c["name"] == "kernel_traces_total"}
         finally:
             obs.configure(trace=was_t, metrics_on=was_m, clear=True)
         assert [m["impl"] for m in marks] == [impl]
-        assert marks[0]["roofline_pred_bytes"] > 0
-        assert "roofline_hbm_us" not in marks[0]
+        assert marks[0]["rows"] == 8
+        assert not any(k.startswith("roofline") for k in marks[0])
         assert counts == {impl: 1}
+
+
+# ---------------------------------------------------------------------------
+# one clock with the device trace: spans as profiler annotations
+# ---------------------------------------------------------------------------
+
+def _host_events(directory) -> list[tuple[str, float, float]]:
+    """(name, start_ns, end_ns) of every event on the host planes of the
+    newest profiler trace under ``directory``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = max(glob.glob(os.path.join(directory, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns))
+    return out
+
+
+class TestProfilerClock:
+    def test_enabled_span_is_on_the_host_plane(self, tmp_path):
+        """An enabled span shows up by name in the profiler's host plane,
+        inside the window annotation that encloses it, and its child
+        nests inside it; the timed-after-the-fact events do not."""
+        import jax
+
+        tr = Tracer(enabled=True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with jax.profiler.TraceAnnotation("bench/window"):
+                with tr.span("prepare/step", w=16):
+                    with tr.span("prepare/readback"):
+                        sum(range(1000))
+                tr.complete("serve/queue_wait", 0, 10)
+                tr.instant("kernel/x/trace")
+        finally:
+            jax.profiler.stop_trace()
+        events = _host_events(str(tmp_path))
+        by_name = {}
+        for name, a, b in events:
+            by_name.setdefault(name, []).append((a, b))
+        (win,) = by_name["bench/window"]
+        (step,) = by_name["prepare/step"]
+        (back,) = by_name["prepare/readback"]
+        assert win[0] <= step[0] <= back[0] <= back[1] <= step[1] <= win[1]
+        assert "serve/queue_wait" not in by_name
+        assert "kernel/x/trace" not in by_name
+        assert [e["name"] for e in tr.events()] == [
+            "prepare/readback", "prepare/step", "serve/queue_wait",
+            "kernel/x/trace"]
+
+    def test_annotation_closes_when_the_block_raises(self, tmp_path):
+        import jax
+
+        tr = Tracer(enabled=True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with jax.profiler.TraceAnnotation("bench/window"):
+                with pytest.raises(KeyError):
+                    with tr.span("build/flatten"):
+                        raise KeyError("boom")
+                with tr.span("build/after"):
+                    pass
+        finally:
+            jax.profiler.stop_trace()
+        spans = {n: (a, b) for n, a, b in _host_events(str(tmp_path))}
+        assert spans["build/flatten"][1] <= spans["build/after"][0]
+        assert [e["name"] for e in tr.events()] == ["build/flatten",
+                                                    "build/after"]
+
+    def test_disabled_span_imports_no_profiler(self, monkeypatch):
+        """The disabled path is one attribute check: NULL_SPAN, and no
+        import of ``jax.profiler`` (here made to fail if tried)."""
+        import sys
+
+        from repro.obs import trace as trace_mod
+
+        monkeypatch.setattr(trace_mod, "_annotation", None)
+        monkeypatch.setitem(sys.modules, "jax.profiler", None)
+        tr = Tracer(enabled=False)
+        with tr.span("serve/route", rows=3) as sp:
+            sp.set(more=1)
+        assert tr.span("serve/scatter") is NULL_SPAN
+        assert trace_mod._annotation is None
+        assert tr.events() == []
+
+    def test_obs_imports_without_jax(self):
+        import subprocess
+        import sys
+        from pathlib import Path
+
+        src = Path(__file__).resolve().parents[1] / "src"
+        code = ("import sys; sys.modules['jax'] = None; "
+                "from repro.obs.trace import Tracer, NULL_SPAN; "
+                "t = Tracer(enabled=True); "
+                "[t.span('a').__enter__().__exit__(None, None, None)]; "
+                "assert [e['name'] for e in t.events()] == ['a']; "
+                "assert Tracer(enabled=False).span('b') is NULL_SPAN")
+        res = subprocess.run([sys.executable, "-c", code], cwd=src,
+                             capture_output=True, text=True, timeout=120)
+        assert res.returncode == 0, res.stderr
+
+
+# ---------------------------------------------------------------------------
+# where the program's spans are: build, prepare loop, server
+# ---------------------------------------------------------------------------
+
+def _inside(child: dict, parent: dict) -> bool:
+    return (child["tid"] == parent["tid"]
+            and child["depth"] > parent["depth"]
+            and parent["ts_ns"] <= child["ts_ns"]
+            and child["ts_ns"] + child["dur_ns"]
+            <= parent["ts_ns"] + parent["dur_ns"])
+
+
+@pytest.fixture
+def traced():
+    from repro import obs
+    was_t, was_m = obs.trace_enabled(), obs.metrics_enabled()
+    obs.configure(trace=True, metrics_on=True, clear=True)
+    try:
+        yield obs
+    finally:
+        obs.configure(trace=was_t, metrics_on=was_m, clear=True)
+
+
+def _small_dna(n=3000):
+    from repro.core.alphabet import DNA
+    from repro.core.api import EraConfig, EraIndexer
+
+    s = DNA.random_string(n, seed=11)
+    # reads of 4 symbols: the prepare loop takes more than one iteration
+    return s, EraIndexer(DNA, EraConfig(memory_bytes=2048, w_max=4,
+                                        build_impl="none"))
+
+
+class TestProgramSpans:
+    def test_build_device_spans_nest(self, traced):
+        s, ix = _small_dna()
+        ix.build_device(s, max_pattern_len=32)
+        ev = traced.tracer().events()
+        named = lambda n: [e for e in ev if e["name"] == n]  # noqa: E731
+        (total,) = named("build/total")
+        (text,) = named("build/device_text")
+        (flat,) = named("build/flatten")
+        (loop,) = named("prepare/batch_loop")
+        assert all(_inside(e, total) for e in (text, flat, loop))
+        steps, backs = named("prepare/step"), named("prepare/readback")
+        assert len(steps) == len(backs) == loop["args"]["iterations"] > 1
+        for st, rb in zip(steps, backs):
+            assert _inside(st, loop) and _inside(rb, st)
+        assert text["ts_ns"] + text["dur_ns"] <= loop["ts_ns"]
+        assert loop["ts_ns"] + loop["dur_ns"] <= flat["ts_ns"]
+
+    def test_serial_prepare_reads_back_inside_the_step(self, traced):
+        from repro.core.api import EraConfig, EraIndexer
+        from repro.core.alphabet import DNA
+
+        s = DNA.random_string(1500, seed=3)
+        EraIndexer(DNA, EraConfig(memory_bytes=2048, build_impl="none",
+                                  w_max=4, construction="serial")).build(s)
+        ev = traced.tracer().events()
+        steps = [e for e in ev if e["name"] == "prepare/step"]
+        backs = [e for e in ev if e["name"] == "prepare/readback"]
+        assert steps and len(steps) == len(backs)
+        assert all(_inside(rb, st) for st, rb in zip(steps, backs))
+
+    @pytest.mark.parametrize("shards", [0, 2])
+    def test_server_spans_one_per_batch(self, traced, shards):
+        from repro.launch.serving import AsyncServer, ServeConfig
+
+        s, ix = _small_dna()
+        dev = (ix.build_sharded(s, n_shards=shards, max_pattern_len=32)
+               if shards else ix.build_device(s, max_pattern_len=32))
+        rng = np.random.default_rng(0)
+        starts = rng.integers(0, len(s) - 40, size=96)
+        pats = [np.asarray(s[i:i + 8 + i % 9], np.int32) for i in starts]
+        pats += pats[:24]                          # cache hits and dups
+        server = AsyncServer(dev, ServeConfig(max_batch=16, cache_size=64,
+                                              pipeline=True))
+        traced.tracer().clear()
+        server.serve(pats)
+        ev = traced.tracer().events()
+        count = lambda n: sum(e["name"] == n for e in ev)  # noqa: E731
+        assert server.n_batches >= len(pats) // 16
+        assert count("serve/route") == server.n_batches
+        assert count("serve/scatter") == server.n_batches
+        assert count("serve/queue_wait") == server.n_batches
+        batches = {c["name"]: c["value"]
+                   for c in traced.metrics().snapshot()["counters"]}
+        assert batches["serve_batches_total"] == server.n_batches
+        # route precedes a batch's pad/packs (one a shard); the scatter
+        # follows its syncs
+        seq = [e["name"] for e in sorted(ev, key=lambda e: e["ts_ns"])
+               if e["name"] in ("serve/route", "serve/pad_pack",
+                                "serve/consume_sync", "serve/scatter")]
+        for a, b in zip(seq, seq[1:]):
+            if b == "serve/pad_pack":
+                assert a == "serve/route" or (shards and a == b)
+            if a == "serve/consume_sync":
+                assert b == "serve/scatter" or (shards and a == b)
