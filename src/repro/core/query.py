@@ -670,49 +670,79 @@ class DeviceIndex:
                 for s, c in bounds]
 
 
+# One fifth of a RouteCache is probation: keys seen once cycle through it
+# alone, so a stream of one-off patterns cannot flush the recurring ones.
+_PROBATION_SHARE = 5
+
+
 class RouteCache:
-    """LRU memo of (route-keyed pattern → (start, count) bounds in ``ell``).
+    """Segmented-LRU memo of (route-keyed pattern → cached answer).
 
     Keyed by :meth:`DeviceIndex.route_key` — exact pattern identity under a
     top-trie route prefix — so the head of a skewed query distribution
     skips the whole binary-search descent; the memoized bounds are exactly
     what the search returns (verdicts are padded-width-independent), which
-    is what makes cache-on/off serving byte-identical.  Plain OrderedDict
-    LRU with hit/miss/eviction counters for the serving driver's stats."""
+    is what makes cache-on/off serving byte-identical.
+
+    Scan-resistant: a ``put`` enters a *probation* segment; a ``get`` that
+    hits there promotes the entry to a *protected* segment of at most
+    ``capacity - max(1, capacity // 5)`` entries, whose overflow is demoted
+    (not evicted) back to probation's MRU end.  Probation's LRU entry is
+    evicted first, so keys seen once displace only each other and the
+    answers that recur stay.  ``capacity`` bounds both segments together.
+    Hit/miss/eviction/promotion/demotion counters feed the serving
+    driver's stats."""
 
     def __init__(self, capacity: int = 4096):
         if capacity < 0:
             raise ValueError(f"capacity={capacity} must be >= 0")
         self.capacity = capacity
-        self._map: collections.OrderedDict[tuple, tuple[int, int]] = \
+        self.protected_capacity = (
+            capacity - max(1, capacity // _PROBATION_SHARE) if capacity else 0)
+        self._probation: collections.OrderedDict[tuple, tuple] = \
+            collections.OrderedDict()
+        self._protected: collections.OrderedDict[tuple, tuple] = \
             collections.OrderedDict()
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        self.promotions = 0
+        self.demotions = 0
 
     def __len__(self) -> int:
-        return len(self._map)
+        return len(self._probation) + len(self._protected)
 
-    def get(self, key) -> tuple[int, int] | None:
-        if self.capacity == 0:
-            self.misses += 1
-            return None
-        got = self._map.get(key)
+    def get(self, key) -> tuple | None:
+        got = self._protected.get(key)
+        if got is not None:
+            self._protected.move_to_end(key)
+            self.hits += 1
+            return got
+        got = self._probation.get(key)
         if got is None:
             self.misses += 1
             return None
-        self._map.move_to_end(key)
         self.hits += 1
+        if not self.protected_capacity:  # capacity 1: probation is all
+            self._probation.move_to_end(key)
+            return got
+        del self._probation[key]
+        self._protected[key] = got
+        self.promotions += 1
+        if len(self._protected) > self.protected_capacity:
+            old, val = self._protected.popitem(last=False)
+            self._probation[old] = val
+            self.demotions += 1
         return got
 
-    def put(self, key, bounds: tuple[int, int]) -> None:
+    def put(self, key, value: tuple) -> None:
         if self.capacity == 0:
             return
-        if key in self._map:
-            self._map.move_to_end(key)
-        self._map[key] = bounds
-        while len(self._map) > self.capacity:
-            self._map.popitem(last=False)
+        seg = self._protected if key in self._protected else self._probation
+        seg[key] = value
+        seg.move_to_end(key)
+        while len(self) > self.capacity:
+            (self._probation or self._protected).popitem(last=False)
             self.evictions += 1
 
     @property
@@ -721,9 +751,12 @@ class RouteCache:
         return self.hits / total if total else 0.0
 
     def stats(self) -> dict:
-        return {"size": len(self._map), "capacity": self.capacity,
+        return {"size": len(self), "capacity": self.capacity,
                 "hits": self.hits, "misses": self.misses,
-                "evictions": self.evictions, "hit_rate": self.hit_rate}
+                "evictions": self.evictions, "hit_rate": self.hit_rate,
+                "promotions": self.promotions, "demotions": self.demotions,
+                "protected": len(self._protected)}
 
     def clear(self) -> None:
-        self._map.clear()
+        self._probation.clear()
+        self._protected.clear()

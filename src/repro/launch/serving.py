@@ -22,8 +22,11 @@ This module adds that tier on top of :class:`repro.core.query.DeviceIndex`:
 * **Hot-prefix route cache** — a :class:`repro.core.query.RouteCache`
   keyed on the dense top-trie route (:meth:`DeviceIndex.route_key`)
   resolves repeated hot patterns at admission, before they cost a batch
-  row; hits skip the whole binary-search descent.  Exact-pattern keys
-  make cache-on results byte-identical to cache-off.
+  row; hits skip the whole binary-search descent.  It is a segmented
+  LRU: a pattern's first answer waits in a probation fifth, and a repeat
+  promotes it to the protected rest, so a stream of one-off patterns
+  cannot evict the answers that recur.  Exact-pattern keys make cache-on
+  results byte-identical to cache-off.
 * **Sharded backend** — hand the server a
   :class:`repro.core.fabric.ShardedIndex` and each admitted batch splits
   by route key into per-shard sub-batches (own pow2 pad/pack, own
@@ -189,6 +192,9 @@ class AsyncServer:
             "serve_cache_hits_total", "route-cache hits at admission")
         self._m_cache_misses = m.counter(
             "serve_cache_misses_total", "route-cache misses at admission")
+        self._m_cache_promotions = m.counter(
+            "serve_cache_promotions_total",
+            "route-cache entries promoted from probation to protected")
         self._m_index_swaps = m.counter(
             "serve_index_swaps_total", "live index generation swaps")
         self._m_cache_flushes = m.counter(
@@ -215,6 +221,10 @@ class AsyncServer:
                 help="route-cache entries (all shards)")
         m.gauge("serve_cache_hit_rate", fn=lambda: self._cache_hit_rate(),
                 help="route-cache lifetime hit rate (all shards)")
+        m.gauge("serve_cache_protected",
+                fn=lambda: sum(c.stats()["protected"] for c in self.caches),
+                help="route-cache entries in the protected segment "
+                     "(all shards)")
         m.gauge("serve_queue_depth_now", fn=lambda: len(self.queue),
                 help="admission-queue depth right now")
 
@@ -251,16 +261,18 @@ class AsyncServer:
         total = hits + sum(c.misses for c in self.caches)
         return hits / total if total else 0.0
 
+    def _cache_promotions(self) -> int:
+        return sum(c.promotions for c in self.caches)
+
     def _cache_stats(self) -> dict:
         if not self.sharded:
             return self.cache.stats()
-        agg = {"size": sum(len(c) for c in self.caches),
-               "capacity": sum(c.capacity for c in self.caches),
-               "hits": sum(c.hits for c in self.caches),
-               "misses": sum(c.misses for c in self.caches),
-               "evictions": sum(c.evictions for c in self.caches),
-               "hit_rate": self._cache_hit_rate()}
-        agg["per_shard"] = [c.stats() for c in self.caches]
+        per_shard = [c.stats() for c in self.caches]
+        agg = {key: sum(st[key] for st in per_shard)
+               for key in ("size", "capacity", "hits", "misses", "evictions",
+                           "promotions", "demotions", "protected")}
+        agg["hit_rate"] = self._cache_hit_rate()
+        agg["per_shard"] = per_shard
         return agg
 
     def _take_batch(self) -> list[_Request] | None:
@@ -308,6 +320,7 @@ class AsyncServer:
         key_row: dict[tuple, int] = {}
         miss_req: list[_Request] = []
         hit_vals: dict[tuple, tuple] = {}
+        promoted = self._cache_promotions()
         with self._tr.span("serve/route", rows=len(requests)):
             keys = [self.dev.route_key(r.pattern) for r in requests]
             for req, key in zip(requests, keys):
@@ -328,6 +341,7 @@ class AsyncServer:
                     key_row[key] = len(miss_req)
                 row_of.append(len(miss_req))
                 miss_req.append(req)
+        self._m_cache_promotions.inc(self._cache_promotions() - promoted)
 
         handles = (hit_vals,)
         n_rows = len(miss_req)
@@ -383,6 +397,7 @@ class AsyncServer:
         key_rows: dict[tuple, list] = {}
         hit_vals: dict[tuple, tuple] = {}
         shard_req: dict[int, list[_Request]] = {}
+        promoted = self._cache_promotions()
         with self._tr.span("serve/route", rows=len(requests)):
             keys = [self.dev.route_key(r.pattern) for r in requests]
             for req, key in zip(requests, keys):
@@ -410,6 +425,7 @@ class AsyncServer:
                 if caching:
                     key_rows[key] = rows
                 row_of.append(rows)
+        self._m_cache_promotions.inc(self._cache_promotions() - promoted)
 
         # shard k -> (real rows, start, count, win) device handles
         shard_handles: dict[int, tuple] = {}

@@ -217,7 +217,9 @@ class TestShardedServing:
         got = srv.serve(pats)
         return ref, got, srv
 
-    @pytest.mark.parametrize("fetch,cache", [(0, 0), (0, 256), (8, 256)])
+    # cache 8: per-shard caches under eviction, promotion and demotion
+    @pytest.mark.parametrize("fetch,cache",
+                             [(0, 0), (0, 256), (8, 256), (0, 8)])
     def test_results_identical(self, fetch, cache):
         ref, got, _ = self._pair(fetch=fetch, cache=cache)
         for i, ((rp, rw), (gp, gw)) in enumerate(zip(ref, got)):
@@ -230,6 +232,8 @@ class TestShardedServing:
         st = srv.stats()["cache"]
         assert st["hits"] > 0
         assert len(st["per_shard"]) == srv.dev.n_shards
+        for key in ("hits", "promotions", "protected"):
+            assert st[key] == sum(p[key] for p in st["per_shard"])
 
 
 class TestWarmstartShardArchives:

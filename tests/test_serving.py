@@ -8,6 +8,7 @@ bookkeeping the benchmarks report: admission-queue overflow, cache
 hit/miss/eviction counters, and the env-var ``ServeConfig`` idiom.
 """
 
+import collections
 import time
 
 import numpy as np
@@ -32,6 +33,15 @@ def dev_and_s():
         memory_bytes=1 << 16, build_impl="none",
         packing="dense")).build_device(s, max_pattern_len=64)
     return dev, s
+
+
+@pytest.fixture()
+def obs_on():
+    from repro import obs
+    was_t, was_m = obs.trace_enabled(), obs.metrics_enabled()
+    obs.configure(trace=True, metrics_on=True, clear=True)
+    yield obs
+    obs.configure(trace=was_t, metrics_on=was_m, clear=True)
 
 
 @pytest.fixture(scope="module")
@@ -187,6 +197,131 @@ class TestRouteCache:
             np.testing.assert_array_equal(g, w)
         assert cache.evictions > 0 and len(cache) <= 3
 
+    @staticmethod
+    def _plain_lru_holds(capacity, puts_and_gets, key):
+        """Whether a plain LRU of ``capacity`` still holds ``key`` after
+        ``puts_and_gets`` (("put"|"get", key) pairs)."""
+        lru = collections.OrderedDict()
+        for op, k in puts_and_gets:
+            if op == "get":
+                if k in lru:
+                    lru.move_to_end(k)
+                continue
+            lru[k] = None
+            lru.move_to_end(k)
+            while len(lru) > capacity:
+                lru.popitem(last=False)
+        return key in lru
+
+    def test_promoted_entry_survives_scan(self):
+        c = RouteCache(capacity=10)
+        ops = [("put", "hot"), ("get", "hot")]
+        ops += [("put", f"once{i}") for i in range(c.capacity)]
+        for op, k in ops:
+            if op == "put":
+                c.put(k, (0, 1))
+            else:
+                c.get(k)
+        assert c.get("hot") == (0, 1)
+        assert not self._plain_lru_holds(c.capacity, ops, "hot")
+        assert c.promotions == 1 and c.demotions == 0
+        assert c.evictions == 1 and len(c) == c.capacity
+
+    def test_probation_hit_promotes_overflow_demotes(self):
+        c = RouteCache(capacity=5)       # protected holds 5 - max(1, 1) = 4
+        assert c.protected_capacity == 4
+        for i in range(5):
+            c.put(i, (i, 1))
+        for i in range(5):               # the fifth promotion demotes 0
+            assert c.get(i) == (i, 1)
+        st = c.stats()
+        assert (st["promotions"], st["demotions"], st["evictions"]) \
+            == (5, 1, 0)
+        assert st["protected"] == 4 and st["size"] == 5
+        c.put("new", (9, 9))             # evicts the demoted 0 first
+        assert c.evictions == 1 and c.get(0) is None
+        assert all(c.get(i) == (i, 1) for i in range(1, 5))
+        assert c.get("new") == (9, 9)    # promoted, demotes 1
+        st = c.stats()
+        assert (st["promotions"], st["demotions"], st["evictions"]) \
+            == (6, 2, 1)
+        assert st["protected"] == 4 and len(c) == 5
+
+    def test_put_of_held_key_keeps_segment(self):
+        c = RouteCache(capacity=5)
+        c.put("p", (0, 1))
+        c.get("p")                       # protected
+        c.put("q", (1, 1))               # probation
+        c.put("p", (2, 2))
+        c.put("q", (3, 3))
+        assert c.stats()["protected"] == 1 and len(c) == 2
+        assert c.promotions == 1 and c.evictions == 0
+        assert c.get("p") == (2, 2) and c.promotions == 1
+        assert c.get("q") == (3, 3) and c.promotions == 2
+
+    def test_capacity_one_stores_and_evicts(self):
+        c = RouteCache(capacity=1)
+        assert c.protected_capacity == 0
+        c.put("a", (0, 1))
+        assert c.get("a") == (0, 1) and c.get("a") == (0, 1)
+        c.put("b", (1, 1))
+        assert c.get("a") is None and c.get("b") == (1, 1)
+        st = c.stats()
+        assert (st["hits"], st["misses"], st["evictions"]) == (3, 1, 1)
+        assert (st["promotions"], st["demotions"], st["protected"]) \
+            == (0, 0, 0)
+        assert len(c) == 1
+
+
+class TestScanResistantServing:
+    BATCH, CACHE = 16, 32
+    HOT_BATCHES = (0, 2, 6, 10, 14)  # gaps of 3-4 batches of one-offs
+
+    def _stream(self, s):
+        """One-off patterns (distinct, never repeated) with one short,
+        high-count pattern first in the batches ``HOT_BATCHES``: after its
+        first repeat, more one-offs pass between its uses than the whole
+        cache holds."""
+        rng = np.random.default_rng(21)
+        hot = np.asarray(s[100:103], np.int32)
+        seen, pats = set(), []
+        for b in range(max(self.HOT_BATCHES) + 1):
+            if b in self.HOT_BATCHES:
+                pats.append(hot)
+            while len(pats) < (b + 1) * self.BATCH:
+                i, m = int(rng.integers(0, len(s) - 20)), int(
+                    rng.integers(8, 17))
+                p = np.asarray(s[i : i + m], np.int32)
+                if p.tobytes() not in seen:
+                    seen.add(p.tobytes())
+                    pats.append(p)
+        return hot, pats
+
+    def test_recurring_pattern_stays_a_hit(self, dev_and_s, obs_on):
+        dev, s = dev_and_s
+        hot, pats = self._stream(s)
+        assert len(dev.find_batch([hot])[0]) > 40
+        gaps = np.diff(self.HOT_BATCHES[1:]) * self.BATCH
+        assert (gaps > self.CACHE).all()  # plain LRU would evict it
+        kw = dict(pipeline=True, max_batch=self.BATCH, max_wait_ms=0.0)
+        off, _ = run_closed_loop(dev, pats, ServeConfig(cache_size=0, **kw))
+        # second, so the registry's gauges read this server
+        on, st = run_closed_loop(dev, pats,
+                                 ServeConfig(cache_size=self.CACHE, **kw))
+        for (p_on, _), (p_off, _), w in zip(on, off, dev.find_batch(pats)):
+            assert p_on.tobytes() == p_off.tobytes()
+            np.testing.assert_array_equal(p_on, w)
+        cache = st["cache"]
+        # every use of the hot pattern after its first is a hit; one-offs
+        # only ever miss
+        assert cache["hits"] == len(self.HOT_BATCHES) - 1
+        assert cache["misses"] == len(pats) - cache["hits"]
+        assert cache["promotions"] == 1 and cache["protected"] == 1
+        m = obs_on.metrics()
+        assert m.counter("serve_cache_promotions_total").value \
+            == cache["promotions"]
+        assert m.gauge("serve_cache_protected").value == cache["protected"]
+
 
 class TestPadBatchBuckets:
     def test_pinned_width_and_rows(self, dev_and_s):
@@ -305,14 +440,6 @@ class TestObservabilityWiring:
     """The serving loop's instrumentation: counters/histograms/spans land
     in the global registry when obs is on, results stay byte-identical,
     and with obs off the server binds only null instruments."""
-
-    @pytest.fixture()
-    def obs_on(self):
-        from repro import obs
-        was_t, was_m = obs.trace_enabled(), obs.metrics_enabled()
-        obs.configure(trace=True, metrics_on=True, clear=True)
-        yield obs
-        obs.configure(trace=was_t, metrics_on=was_m, clear=True)
 
     def test_registry_wiring_closed_loop(self, dev_and_s, workload, obs_on):
         dev, _ = dev_and_s
